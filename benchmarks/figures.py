@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import ctmc, ising, problems, sampler_api, samplers
 from repro.core.glauber import LAMBDA0_CHIP_HZ
+from repro.kernels import ops
 
 FAST = False
 
@@ -309,12 +310,9 @@ def driver():
         prob, sampler_api.TauLeap(dt=0.25), key, n_steps=steps_p, backend="pallas"
     ).s
     us = _timeit(lambda: jax.block_until_ready(fn(jax.random.key(3))), n=2)
-    on_tpu = jax.default_backend() == "tpu"
-    _row(
-        "driver/tau_leap_pallas",
-        us,
-        f"us_per_step={us/steps_p:.2f};mode={'compiled' if on_tpu else 'interpret'}",
-    )
+    # the label comes from the same platform check that picks interpret mode
+    mode = "compiled" if ops.on_tpu() else "interpret"
+    _row("driver/tau_leap_pallas", us, f"us_per_step={us/steps_p:.2f};mode={mode}")
 
 
 def fig5_decision():
@@ -340,7 +338,6 @@ def fig5_decision():
 def kernels():
     """Kernel wall time (reference path jitted on CPU; the Pallas kernels
     themselves are TPU-targeted and validated in interpret mode by tests)."""
-    from repro.kernels import ops
     from repro.core.ising import king_color_masks
 
     B, H, W = 256, 16, 16

@@ -9,7 +9,8 @@ Schema (version 2):
     {
       "schema_version": 2,
       "tag": "...", "suite": "smoke", "created_unix": 1e9,
-      "host": {"platform": ..., "python": ..., "jax": ..., "backend": ...},
+      "host": {"platform": ..., "python": ..., "jax": ..., "backend": ...,
+               "device_kind": ..., "device_count": ...},
       "statuses": {"ok": 12, "timeout": 1, ...},
       "records": [ {<runner.run_entry record>}, ... ],
       "robustness": {<benchmarks.robustness section>}   # optional
@@ -60,14 +61,34 @@ def git_commit() -> "str | None":
     return out.stdout.strip() or None if out.returncode == 0 else None
 
 
+def use_compile_cache(root: str = REPO_ROOT) -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX has already read it and it
+    is left alone. Otherwise the cache goes to `<root>/.jax_cache`: a fixed
+    path, because the path is part of the cache key, so a directory that
+    moved would never hit. Entry points call this (`benchmarks.run`,
+    `chip_smoke.py`); importing the package never does.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(root, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def host_info() -> dict:
-    """Host identity header for a report (platform, jax, CI flag, commit)."""
+    """Host identity header for a report (platform, device, jax, CI flag,
+    commit)."""
+    devices = jax.devices()
     return {
         "platform": platform.platform(),
         "machine": platform.machine(),
         "python": sys.version.split()[0],
         "jax": jax.__version__,
         "backend": jax.default_backend(),
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         # True when produced by a GitHub Actions runner: only then are the
         # absolute throughput numbers comparable to later CI runs, and only
         # then does the regression gate fail hard (see compare_to_baseline).

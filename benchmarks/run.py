@@ -144,7 +144,8 @@ def main(argv: list[str] | None = None) -> int:
     )
     print(f"suite wall time: {time.perf_counter() - t0:.1f}s")
     statuses = report_mod.status_counts(records)
-    if set(statuses) - {"ok"}:
+    failed = set(statuses) - {"ok"}
+    if failed:
         print(f"entry statuses: {statuses}")
 
     scaling_section = None
@@ -192,8 +193,17 @@ def main(argv: list[str] | None = None) -> int:
         print(report_mod.format_comparison(summary))
         if not ok:
             return 1
+    if failed:
+        # The report above keeps every record; the exit code must not hide
+        # an entry that timed out or errored.
+        print(f"FAILED: {len(records) - statuses.get('ok', 0)} of {len(records)} "
+              f"entries did not finish ok")
+        return 1
     return 0
 
 
 if __name__ == "__main__":
+    # Only the command line places the compile cache: tests call main()
+    # and must not start writing one.
+    report_mod.use_compile_cache()
     sys.exit(main())

@@ -22,7 +22,14 @@ yields a record whose `status` is one of
                 recorded so the report accounts for every entry.
 
 Non-ok records keep the identity fields and carry `error` instead of
-metrics; `benchmarks.report` filters on status for baselines/gating.
+metrics; `benchmarks.report` filters on status for baselines/gating, and
+`benchmarks.run` exits nonzero when any record is not "ok".
+
+With isolate=True the parent never initialises a JAX backend before its
+children run: it builds no problem and calls no JAX function until the
+last child has exited (the report's host header is read after the suite).
+On a TPU host the process that initialises the backend holds the chip, so
+a child started after that would fail or hang.
 """
 from __future__ import annotations
 
@@ -217,7 +224,8 @@ def error_record(entry: SuiteEntry, status: str, error: Optional[str]) -> dict:
 def _run_entry_subprocess(entry: SuiteEntry, timeout_s: Optional[float]) -> dict:
     """Run one entry in a `benchmarks.entry_worker` child process.
 
-    Raises EntryTimeout when the child exceeds `timeout_s` (it is killed),
+    The parent must not have initialised a JAX backend at this point (see
+    the module docstring): the child needs the device to itself. Raises EntryTimeout when the child exceeds `timeout_s` (it is killed),
     RuntimeError (with the stderr tail) when it exits nonzero or writes no
     record.
     """

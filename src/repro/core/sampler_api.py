@@ -1057,7 +1057,9 @@ def _resolve_backend(backend: Optional[str], kernel=None, problem=None) -> Optio
         raise ValueError(f"backend must be 'ref' | 'pallas' | 'auto', got {backend!r}")
     supported = ("ref", "pallas") if kernel is None else kernel_backends(kernel, problem)
     if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" and "pallas" in supported else "ref"
+        from repro.kernels import ops
+
+        return "pallas" if ops.on_tpu() and "pallas" in supported else "ref"
     if backend not in supported:
         name = getattr(kernel, "name", type(kernel).__name__)
         raise ValueError(

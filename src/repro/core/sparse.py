@@ -91,14 +91,14 @@ class SparseIsing:
     def neighbor_sum(self, s: jax.Array) -> jax.Array:
         """sum_j J_ij s_j via one padded gather. s: (..., n) ±1 -> (..., n).
 
-        Padded slots gather the site's own spin but multiply by weight 0;
-        the single vectorized gather+reduce is the exact expression the
-        Pallas sweep kernel evaluates, so ref/kernel paths agree bit-for-bit
-        in interpret mode.
+        Padded slots gather the site's own spin but multiply by weight 0.
+        The slots are added in `slot_sum`'s fixed order, the order the
+        Pallas sweep kernel adds them in, so ref/kernel paths agree
+        bit-for-bit in interpret mode.
         """
         s = s.astype(self.nbr_w.dtype)
         gathered = jnp.take(s, self.nbr_idx, axis=-1)  # (..., n, max_deg)
-        return jnp.sum(self.nbr_w * gathered, axis=-1)
+        return slot_sum(self.nbr_w, gathered)
 
     def local_fields(self, s: jax.Array) -> jax.Array:
         """h_i = sum_j J_ij s_j + b_i (batched)."""
@@ -249,6 +249,19 @@ class SparseIsing:
             live = ~pad
             if np.any(colors[idx][live] == colors[:, None].repeat(md, 1)[live]):
                 raise ValueError("color_masks is not a proper coloring (edge within a color)")
+
+
+def slot_sum(nbr_w: jax.Array, gathered: jax.Array) -> jax.Array:
+    """sum_k nbr_w[:, k] * gathered[..., k], added slot by slot from k = 0.
+
+    The order is written out because a vectorized reduce leaves it to XLA,
+    which may pick a different one in each program; with it fixed, the ref
+    path, the jnp oracle and the Pallas kernels round alike.
+    """
+    acc = nbr_w[:, 0] * gathered[..., 0]
+    for k in range(1, nbr_w.shape[-1]):
+        acc = acc + nbr_w[:, k] * gathered[..., k]
+    return acc
 
 
 def color_graph(nbr_idx: np.ndarray, deg: np.ndarray) -> np.ndarray:

@@ -27,8 +27,8 @@ def _dense_field_kernel(s_ref, jt_ref, b_ref, scale_ref, out_ref, acc_ref, *, nk
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        s_ref[...].astype(jnp.int32),
-        jt_ref[...].astype(jnp.int32),
+        s_ref[...],
+        jt_ref[...],
         preferred_element_type=jnp.int32,
     )
 

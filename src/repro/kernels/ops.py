@@ -19,53 +19,55 @@ from repro.kernels import ref as _ref
 from repro.kernels import tau_leap as _tl
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
+    """The platform check every wrapper and the driver's "auto" share:
+    kernels compile on a TPU and run in interpret mode elsewhere."""
     return jax.default_backend() == "tpu"
 
 
 def lattice_gibbs_sweep(
     s, w, b, uniforms, colors, frozen, clamp_value, beta=None, mode: str = "auto", **kw
 ):
-    if mode == "reference" or (mode == "auto" and not _on_tpu()):
+    if mode == "reference" or (mode == "auto" and not on_tpu()):
         cm = colors > 0.5
         fz = frozen > 0.5
         return _ref.lattice_gibbs_sweep_ref(s, w, b, uniforms, cm, fz, clamp_value, beta)
     # batch/block_batch divisibility is validated inside the kernel wrapper
     # (a readable ValueError at call/trace time, not a Pallas grid error)
     return _lg.lattice_gibbs_sweep(
-        s, w, b, uniforms, colors, frozen, clamp_value, beta, interpret=not _on_tpu(), **kw
+        s, w, b, uniforms, colors, frozen, clamp_value, beta, interpret=not on_tpu(), **kw
     )
 
 
 def sparse_fields(s, nbr_idx, nbr_w, b, mode: str = "auto", **kw):
-    if mode == "reference" or (mode == "auto" and not _on_tpu()):
+    if mode == "reference" or (mode == "auto" and not on_tpu()):
         return _ref.sparse_fields_ref(s, nbr_idx, nbr_w, b)
     from repro.kernels import sparse_gather as _sg
 
-    return _sg.sparse_fields(s, nbr_idx, nbr_w, b, interpret=not _on_tpu(), **kw)
+    return _sg.sparse_fields(s, nbr_idx, nbr_w, b, interpret=not on_tpu(), **kw)
 
 
 def colored_gibbs_sweep(s, nbr_idx, nbr_w, b, uniforms, masks, beta=None, mode: str = "auto", **kw):
-    if mode == "reference" or (mode == "auto" and not _on_tpu()):
+    if mode == "reference" or (mode == "auto" and not on_tpu()):
         return _ref.colored_gibbs_sweep_ref(s, nbr_idx, nbr_w, b, uniforms, masks > 0.5, beta)
     from repro.kernels import sparse_gather as _sg
 
     # batch/block_batch divisibility is validated inside the kernel wrapper
     return _sg.colored_gibbs_sweep(
-        s, nbr_idx, nbr_w, b, uniforms, masks, beta, interpret=not _on_tpu(), **kw
+        s, nbr_idx, nbr_w, b, uniforms, masks, beta, interpret=not on_tpu(), **kw
     )
 
 
 def dense_field(s_i8, j_i8, b, scale, mode: str = "auto", **kw):
-    if mode == "reference" or (mode == "auto" and not _on_tpu()):
+    if mode == "reference" or (mode == "auto" and not on_tpu()):
         return _ref.dense_field_ref(s_i8, j_i8, b, scale)
-    return _df.dense_field(s_i8, j_i8, b, scale, interpret=not _on_tpu(), **kw)
+    return _df.dense_field(s_i8, j_i8, b, scale, interpret=not on_tpu(), **kw)
 
 
 def tau_leap_step(s, j_i8, b, scale, uniforms, dt, mode: str = "auto", **kw):
-    if mode == "reference" or (mode == "auto" and not _on_tpu()):
+    if mode == "reference" or (mode == "auto" and not on_tpu()):
         return _ref.tau_leap_step_ref(s, j_i8, b, scale, uniforms, dt)
-    return _tl.tau_leap_step(s, j_i8, b, scale, uniforms, dt, interpret=not _on_tpu(), **kw)
+    return _tl.tau_leap_step(s, j_i8, b, scale, uniforms, dt, interpret=not on_tpu(), **kw)
 
 
 def quantize_dense(J: jax.Array, bits: int = 8) -> tuple[jax.Array, jax.Array]:
@@ -81,6 +83,6 @@ def flash_attention(q, k, v, causal=True, mode: str = "auto", **kw):
     """(BH, S, d) fused attention; oracle on CPU, Pallas kernel on TPU."""
     from repro.kernels import flash_attention as _fa
 
-    if mode == "reference" or (mode == "auto" and not _on_tpu()):
+    if mode == "reference" or (mode == "auto" and not on_tpu()):
         return _ref.flash_attention_ref(q, k, v, causal=causal)
-    return _fa.flash_attention(q, k, v, causal=causal, interpret=not _on_tpu(), **kw)
+    return _fa.flash_attention(q, k, v, causal=causal, interpret=not on_tpu(), **kw)

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.ising import KING_OFFSETS, shift2d
+from repro.core.sparse import slot_sum
 
 
 def lattice_fields_ref(s: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
@@ -54,10 +55,10 @@ def sparse_fields_ref(
 ) -> jax.Array:
     """Padded neighbor-list local fields. s: (B,n) ±1; nbr_idx/nbr_w:
     (n,max_deg); b: (n,). Padded slots index the site itself with weight 0.
-    The gather+reduce is the exact expression `SparseIsing.neighbor_sum`
-    and the Pallas kernel evaluate — bit-parity by construction."""
+    The slots are added in `slot_sum`'s order, as `SparseIsing.neighbor_sum`
+    and the Pallas kernel add them — bit-parity by construction."""
     gathered = jnp.take(s, nbr_idx, axis=-1)  # (B, n, max_deg)
-    return jnp.sum(nbr_w * gathered, axis=-1) + b
+    return slot_sum(nbr_w, gathered) + b
 
 
 def colored_gibbs_sweep_ref(

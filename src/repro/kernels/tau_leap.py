@@ -38,8 +38,8 @@ def _tau_leap_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        s_mat_ref[...].astype(jnp.int32),
-        jt_ref[...].astype(jnp.int32),
+        s_mat_ref[...],
+        jt_ref[...],
         preferred_element_type=jnp.int32,
     )
 

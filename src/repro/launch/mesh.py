@@ -12,15 +12,15 @@ from __future__ import annotations
 
 import jax
 
-from repro.sharding.partition import make_mesh_compat
+from repro.sharding.partition import make_auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
     """Small mesh over however many devices exist (tests/CI)."""
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)

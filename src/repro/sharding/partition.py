@@ -32,14 +32,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _state = threading.local()
 
 
-def make_mesh_compat(shape, axes) -> Mesh:
-    """jax.make_mesh across jax versions: `axis_types` (and
-    `jax.sharding.AxisType`) only exist from jax 0.5; the pinned 0.4.x
-    builds meshes without it (every axis is Auto there anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+def make_auto_mesh(shape, axes) -> Mesh:
+    """jax.make_mesh with every axis Auto (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 DEFAULT_RULES: dict[str, object] = {

@@ -713,3 +713,79 @@ def test_robustness_grids_cover_acceptance_axes():
         assert grid in robustness_mod.SANITY_SPECS
     with pytest.raises(KeyError, match="grid"):
         robustness_mod.robustness_section("warp", log=lambda m: None)
+
+
+def test_cli_exits_nonzero_when_an_entry_fails(tmp_path, monkeypatch):
+    """A suite with a failed entry still writes its whole report, and the
+    exit code says that it failed."""
+    monkeypatch.setitem(
+        suites.SUITES, "tiny", lambda: [_tiny_entry(seed=0), _tiny_entry(seed=1)]
+    )
+    real_run_entry = runner.run_entry
+
+    def failing_second(entry, zoo=None):
+        if entry.seed == 1:
+            raise RuntimeError("injected failure")
+        return real_run_entry(entry, zoo)
+
+    monkeypatch.setattr(runner, "run_entry", failing_second)
+    rc = run_cli.main(["--suite", "tiny", "--tag", "bad", "--out", str(tmp_path),
+                       "--retries", "0"])
+    assert rc == 1
+    rep = report_mod.load(str(tmp_path / "BENCH_bad.json"))
+    assert rep["statuses"] == {"ok": 1, "error": 1}
+
+
+def test_host_info_names_the_device():
+    import jax
+
+    host = report_mod.host_info()
+    assert host["backend"] == jax.devices()[0].platform
+    assert host["device_kind"] == jax.devices()[0].device_kind
+    assert host["device_count"] == len(jax.devices())
+
+
+def test_compile_cache_placement(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and is left alone; without it the
+    cache goes to the fixed <root>/.jax_cache."""
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+        assert report_mod.use_compile_cache(str(tmp_path)) == str(tmp_path / "outside")
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = report_mod.use_compile_cache(str(tmp_path))
+        assert path == str(tmp_path / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_isolated_suite_parent_stays_off_the_device(tmp_path):
+    """With isolate=True no JAX backend exists in the parent when a child
+    starts: on a TPU host the parent would otherwise hold the chip."""
+    import os
+    import subprocess
+    import sys
+
+    script = tmp_path / "parent.py"
+    script.write_text(
+        "import json\n"
+        "from jax._src import xla_bridge\n"
+        "from benchmarks import runner, suites\n"
+        "seen = []\n"
+        "def child(entry, timeout_s):\n"
+        "    seen.append(bool(xla_bridge._backends))\n"
+        "    raise RuntimeError('no child in this test')\n"
+        "runner._run_entry_subprocess = child\n"
+        "entries = suites.get_suite('smoke')[:2]\n"
+        "runner.run_suite(entries, log=lambda m: None, isolate=True, retries=0)\n"
+        "print(json.dumps(seen))\n"
+    )
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join([runner.SRC_DIR, runner.REPO_ROOT]))
+    out = subprocess.run([sys.executable, str(script)], cwd=runner.REPO_ROOT, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert json.loads(out.stdout.strip().splitlines()[-1]) == [False, False]
