@@ -74,7 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import diagnostics as diag
-from repro.core import event_tree, glauber
+from repro.core import event_tree, glauber, tracing
 from repro.core.diagnostics import RunDiagnostics  # noqa: F401  (re-export)
 from repro.core.faults import FaultModel  # noqa: F401  (re-export)
 from repro.core.ising import DenseIsing, LatticeIsing, king_color_masks
@@ -1306,62 +1306,72 @@ def run(
         kernels. None (the default) compiles the exact fault-free program
         — results are bit-identical to a run that never passed the
         argument, for every kernel and backend.
+
+    Each call that runs (is not traced by an outer `jax.jit`) opens the
+    host spans `run`, `run.validate`, `run.prep` and `run.call` and leaves
+    one record of their durations: see `repro.core.tracing`. They touch
+    only the host.
     """
-    if isinstance(kernel, str):
-        kernel = get_kernel(kernel)
-    check_problem_kind(kernel, problem)
-    resolved = _resolve_backend(backend, kernel, problem)
-    if resolved is not None and hasattr(kernel, "backend") and kernel.backend != resolved:
-        kernel = dataclasses.replace(kernel, backend=resolved)
+    with tracing.call() as span:
+        with span("run.validate"):
+            if isinstance(kernel, str):
+                kernel = get_kernel(kernel)
+            check_problem_kind(kernel, problem)
+            resolved = _resolve_backend(backend, kernel, problem)
+            if resolved is not None and hasattr(kernel, "backend") and kernel.backend != resolved:
+                kernel = dataclasses.replace(kernel, backend=resolved)
 
-    if faults is not None:
-        faults.validate(problem)
-        problem, faults = faults.bind(problem)
-    # Fail loudly on a problem whose couplings/biases cannot produce finite
-    # energies (NaN/Inf snuck past construction, or an over-aggressive
-    # fault model) — otherwise every recorded energy is NaN and the TTS
-    # fits in `observables.fit_scaling` silently degrade. The probe is a
-    # host-side check: when run() is itself being traced (e.g. inside the
-    # jitted tempering loop) the energy is a tracer and the check is
-    # skipped — concreteness is gone, and the caller's own entry into jit
-    # already went through an un-traced run() or can probe explicitly.
-    e_probe = problem.energy(jnp.ones(state_shape(problem)))
-    if not isinstance(e_probe, jax.core.Tracer) and not bool(jnp.isfinite(e_probe)):
-        raise NonFiniteEnergyError(
-            f"problem energy is non-finite (probe energy {float(e_probe)}); "
-            "check the couplings/biases (and any FaultModel) for NaN/Inf"
-        )
+            if faults is not None:
+                faults.validate(problem)
+                problem, faults = faults.bind(problem)
+            # Fail loudly on a problem whose couplings/biases cannot produce
+            # finite energies (NaN/Inf snuck past construction, or an
+            # over-aggressive fault model) — otherwise every recorded energy
+            # is NaN and the TTS fits in `observables.fit_scaling` silently
+            # degrade. The probe is a host-side check: when run() is itself
+            # being traced (e.g. inside the jitted tempering loop) the energy
+            # is a tracer and the check is skipped — concreteness is gone,
+            # and the caller's own entry into jit already went through an
+            # un-traced run() or can probe explicitly.
+            e_probe = problem.energy(jnp.ones(state_shape(problem)))
+            if not isinstance(e_probe, jax.core.Tracer) and not bool(jnp.isfinite(e_probe)):
+                raise NonFiniteEnergyError(
+                    f"problem energy is non-finite (probe energy {float(e_probe)}); "
+                    "check the couplings/biases (and any FaultModel) for NaN/Inf"
+                )
 
-    betas = resolve_schedule(schedule, n_steps, n_chains)
-    track_hit = first_hit is not None
-    e_target = jnp.asarray(first_hit if track_hit else jnp.inf, jnp.float32)
-    unroll = _resolve_unroll(unroll, kernel, problem)
+        with span("run.prep"):
+            betas = resolve_schedule(schedule, n_steps, n_chains)
+            track_hit = first_hit is not None
+            e_target = jnp.asarray(first_hit if track_hit else jnp.inf, jnp.float32)
+            unroll = _resolve_unroll(unroll, kernel, problem)
 
-    if n_chains == 1:
-        call = lambda: _run_single(
-            problem, kernel, key, s0, betas, e_target, n_steps, sample_every,
-            track_hit, unroll, diagnostics, faults,
-        )
-    else:
-        keys = jax.random.split(key, n_chains)
-        call = lambda: _run_batched(
-            problem, kernel, keys, s0, betas, e_target, n_steps, sample_every,
-            track_hit, n_chains, unroll, diagnostics, faults,
-        )
+            if n_chains == 1:
+                call = lambda: _run_single(
+                    problem, kernel, key, s0, betas, e_target, n_steps, sample_every,
+                    track_hit, unroll, diagnostics, faults,
+                )
+            else:
+                keys = jax.random.split(key, n_chains)
+                call = lambda: _run_batched(
+                    problem, kernel, keys, s0, betas, e_target, n_steps, sample_every,
+                    track_hit, n_chains, unroll, diagnostics, faults,
+                )
 
-    if not timeit:
-        return call()
+        with span("run.call"):
+            if not timeit:
+                return call()
 
-    t0 = time.perf_counter()
-    jax.block_until_ready(call())
-    first_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    res = jax.block_until_ready(call())
-    wall_s = max(time.perf_counter() - t0, 1e-9)
-    timing = RunTiming(
-        compile_s=max(0.0, first_s - wall_s),
-        wall_s=wall_s,
-        steps_per_s=n_steps / wall_s,
-        chain_steps_per_s=n_steps * n_chains / wall_s,
-    )
-    return res._replace(timing=timing)
+            t0 = time.perf_counter()
+            jax.block_until_ready(call())
+            first_s = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            res = jax.block_until_ready(call())
+            wall_s = max(time.perf_counter() - t0, 1e-9)
+            timing = RunTiming(
+                compile_s=max(0.0, first_s - wall_s),
+                wall_s=wall_s,
+                steps_per_s=n_steps / wall_s,
+                chain_steps_per_s=n_steps * n_chains / wall_s,
+            )
+            return res._replace(timing=timing)
