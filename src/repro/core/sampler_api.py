@@ -587,6 +587,15 @@ class ColoredGibbs:
         return KernelState(s=s, t=state.t + 1.0 / self.lambda0, e=None, aux=())
 
 
+# run(unroll="auto") on the compiled dense tau-leap kernel: steps per scan
+# iteration. A step is then about five device operations (draw, kernel,
+# first-hit energy), the scan's own per-iteration work paid once a block;
+# one step per iteration took 13 (TPU v5e, SK n=2048, 128 chains), and a
+# profiler trace, which keeps about 5M of them, then lost most of a 45 s
+# window.
+TAU_LEAP_BLOCK_STEPS = 32
+
+
 @register_kernel("tau_leap")
 @partial(
     jax.tree_util.register_dataclass,
@@ -620,6 +629,16 @@ class TauLeap:
         if isinstance(problem, (LatticeIsing, SparseIsing)) or self.trim is not None:
             return ("ref",)
         return self.backends
+
+    def preferred_unroll(self, problem) -> int:
+        """Steps per scan iteration for run(unroll="auto"): a block of
+        `TAU_LEAP_BLOCK_STEPS` where every step is a call of the compiled
+        dense kernel (backend "pallas" on a TPU), 1 elsewhere."""
+        from repro.kernels import ops
+
+        if self.backend == "pallas" and isinstance(problem, DenseIsing) and ops.on_tpu():
+            return TAU_LEAP_BLOCK_STEPS
+        return 1
 
     def init(self, problem, key, s0=None, faults=None) -> KernelState:
         """Initial state (int8-quantized weights under pallas)."""

@@ -19,6 +19,16 @@ with `time.perf_counter_ns()` into the call's record.
 calls are kept. A `run()` traced by an outer transformation (the jitted
 tempering loop) times tracing, not running, and leaves no span and no
 record. A call that raises leaves no record.
+
+Row occupancy. A Pallas kernel whose rows are chains notes, each time a
+program calling it is traced, the rows that carry a chain per call, the
+rows the MXU processes per call (those padded to its row tile) and the
+calls per step; `row_occupancy(kernel)` reads the last note. A program
+served from JAX's caches is not traced again, so the note is that of the
+last program traced, which is the one running wherever one program is
+traced and then called (the benchmark's cells). `run()` on `n_chains`
+chains of dense tau-leap reads `n_chains` of `n_chains` rounded up to 128
+rows in one call per step, where it read 1 of 128 rows in `n_chains`.
 """
 from __future__ import annotations
 
@@ -26,7 +36,7 @@ import collections
 import contextlib
 import itertools
 import time
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 
@@ -88,3 +98,24 @@ def recent(k: int) -> list[CallRecord]:
     if k <= 0:
         return []
     return list(itertools.islice(_records, max(0, len(_records) - k), None))
+
+
+class RowOccupancy(NamedTuple):
+    """A kernel's rows, as the last program traced that calls it holds them."""
+
+    rows: int    # rows that carry a chain, per call
+    padded: int  # rows the MXU processes per call: `rows` padded to its row tile
+    calls: int   # calls per step
+
+
+_occupancy: dict[str, RowOccupancy] = {}
+
+
+def note_rows(kernel: str, rows: int, padded: int, calls: int) -> None:
+    """Note the rows of `kernel`'s calls in the program being traced."""
+    _occupancy[kernel] = RowOccupancy(rows, padded, calls)
+
+
+def row_occupancy(kernel: str) -> Optional[RowOccupancy]:
+    """The last note of `kernel`'s rows, or None if none was traced."""
+    return _occupancy.get(kernel)

@@ -65,9 +65,11 @@ def dense_field(s_i8, j_i8, b, scale, mode: str = "auto", **kw):
 
 
 def tau_leap_step(s, j_i8, b, scale, uniforms, dt, mode: str = "auto", **kw):
+    """Dense tau-leap step; under `jax.vmap` the kernel's mapped calls become
+    the rows of one call (`tau_leap.tau_leap_rows`)."""
     if mode == "reference" or (mode == "auto" and not on_tpu()):
         return _ref.tau_leap_step_ref(s, j_i8, b, scale, uniforms, dt)
-    return _tl.tau_leap_step(s, j_i8, b, scale, uniforms, dt, interpret=not on_tpu(), **kw)
+    return _tl.tau_leap_rows(s, j_i8, b, scale, uniforms, dt, interpret=not on_tpu(), **kw)
 
 
 def quantize_dense(J: jax.Array, bits: int = 8) -> tuple[jax.Array, jax.Array]:

@@ -92,12 +92,13 @@ def dense_field_ref(s_i8: jax.Array, j_i8: jax.Array, b: jax.Array, scale: jax.A
     """int8 binary dot-product engine: h = (s @ J^T) * scale + b.
 
     s_i8: (B,N) int8 in {-1,+1}; j_i8: (N,N) int8 weight codes;
-    scale: () f32 dequant scale; b: (N,) f32. Returns (B,N) f32.
+    scale: () f32 dequant scale, or (B,) one per row; b: (N,) f32, or
+    (B,N) one per row. Returns (B,N) f32.
     """
     acc = jnp.dot(
         s_i8.astype(jnp.int32), j_i8.astype(jnp.int32).T, preferred_element_type=jnp.int32
     )
-    return acc.astype(jnp.float32) * scale + b[None, :]
+    return acc.astype(jnp.float32) * jnp.reshape(scale, (-1, 1)) + b
 
 
 def tau_leap_step_ref(
@@ -110,7 +111,8 @@ def tau_leap_step_ref(
 ) -> jax.Array:
     """Fused dense tau-leap PASS update.
 
-    s: (B,N) f32 ±1. Flip each spin w.p. 1-exp(-dt*sigma(2 h s)).
+    s: (B,N) f32 ±1. Flip each spin w.p. 1-exp(-dt*sigma(2 h s)). scale
+    and b are shared, or given per row (see `dense_field_ref`).
     """
     h = dense_field_ref(s.astype(jnp.int8), j_i8, b, scale)
     rate = jax.nn.sigmoid(2.0 * h * s)

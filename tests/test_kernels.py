@@ -57,18 +57,52 @@ def test_dense_field_kernel_matches_ref(B, N, blocks):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("B,N", [(8, 64), (32, 200), (128, 128)])
-def test_tau_leap_kernel_matches_ref(B, N):
-    k = jax.random.split(jax.random.key(2), 4)
+@pytest.mark.parametrize(
+    "B,N,per_row,block",
+    [
+        pytest.param(8, 64, (), 64, id="8-64"),
+        pytest.param(32, 200, (), 64, id="32-200"),
+        pytest.param(128, 128, (), 64, id="128-128"),
+        # per-row operands, as a batched run() passes them
+        pytest.param(8, 64, ("scale",), 64, id="8-64-row_scale"),
+        pytest.param(32, 200, ("bias",), 64, id="32-200-row_bias"),
+        pytest.param(130, 128, ("scale", "bias"), 64, id="130-128-row_scale_bias"),
+        # the kernel's own blocks: N padded to 1024, 512 columns by 1024 sites
+        pytest.param(8, 1000, ("scale", "bias"), None, id="8-1000-default_blocks"),
+    ],
+)
+def test_tau_leap_kernel_matches_ref(B, N, per_row, block):
+    k = jax.random.split(jax.random.key(2), 6)
     s = _rand_pm1(k[0], (B, N))
     J = jax.random.randint(k[1], (N, N), -127, 128, jnp.int32).astype(jnp.int8)
     b = jax.random.normal(k[2], (N,)) * 0.2
     u = jax.random.uniform(k[3], (B, N))
     scale = jnp.asarray(1.0 / 127.0, jnp.float32)
     dt = jnp.asarray(0.3, jnp.float32)
-    got = tl.tau_leap_step(s, J, b, scale, u, dt, block_b=64, block_n=64, block_k=64, interpret=True)
+    if "scale" in per_row:
+        scale = scale * jax.random.uniform(k[4], (B,), minval=0.1, maxval=3.0)
+    if "bias" in per_row:
+        b = b + jax.random.normal(k[5], (B, N)) * 0.5
+    blocks = {} if block is None else dict(block_b=block, block_n=block, block_k=block)
+    got = tl.tau_leap_step(s, J, b, scale, u, dt, interpret=True, **blocks)
     want = ref.tau_leap_step_ref(s, J, b, scale, u, dt)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0)
+
+
+@pytest.mark.parametrize("C", [1, 3, 128])
+def test_tau_leap_row_occupancy(C):
+    """run() on C chains puts them in the rows of ONE kernel call per step:
+    C of 128 rows, where each chain used to be 1 of 128 rows in a call of
+    its own. The note is made while the program is traced, so each case
+    runs a shape no other test runs."""
+    from repro.core import ising, sampler_api, tracing
+
+    n = 40
+    J = jax.random.randint(jax.random.key(4), (n, n), -127, 128, jnp.int32) / 127.0
+    prob = ising.DenseIsing(J=jnp.triu(J, 1) + jnp.triu(J, 1).T, b=jnp.zeros((n,)))
+    sampler_api.run(prob, sampler_api.TauLeap(dt=0.25), jax.random.key(0), n_steps=5,
+                    n_chains=C, backend="pallas")
+    assert tracing.row_occupancy("tau_leap_step") == tracing.RowOccupancy(C, 128, 1)
 
 
 def test_dense_field_int8_exactness():

@@ -28,17 +28,22 @@ def _dense_problem(n=12, seed=0, scale=0.6):
     return ising.DenseIsing(J=jnp.asarray(J, jnp.float32), b=jnp.asarray(b, jnp.float32))
 
 
-def _grid_exact_problem(n=48, seed=0):
+def _grid_exact_problem(n=48, seed=0, denom=127):
     """Dense problem whose J sits exactly on the int8 grid, so the Pallas
-    path's quantization is lossless and ref/pallas are comparable."""
+    path's quantization is lossless and ref/pallas are comparable.
+
+    `denom=128` puts J and b on multiples of 1/128 as well: every energy
+    is then a sum of exact terms, the same whatever order it is added in."""
     rng = np.random.default_rng(seed)
     codes = rng.integers(-126, 127, (n, n))
     codes = np.triu(codes, 1)
     codes = codes + codes.T
     codes[0, 1] = codes[1, 0] = 127  # pin max-abs: quantize round-trips exactly
-    J = jnp.asarray(codes / 127.0, jnp.float32)
-    b = jnp.asarray(rng.normal(0, 0.2, n), jnp.float32)
-    return ising.DenseIsing(J=J, b=b)
+    J = jnp.asarray(codes / denom, jnp.float32)
+    b = rng.normal(0, 0.2, n)
+    if denom == 128:
+        b = np.round(b * 128) / 128
+    return ising.DenseIsing(J=J, b=jnp.asarray(b, jnp.float32))
 
 
 def test_registry_has_all_kernels():
@@ -93,6 +98,49 @@ def test_pallas_backend_matches_ref_dense_tau_leap():
     np.testing.assert_allclose(
         np.asarray(r_ref.energies), np.asarray(r_pal.energies), rtol=1e-3, atol=1e-2
     )
+
+
+@pytest.mark.parametrize("C", [3, 128, 130])
+@pytest.mark.parametrize("variant", ["shared_schedule", "per_chain_schedule", "faults"])
+def test_pallas_chains_as_rows_match_single_chain_runs(variant, C):
+    """Under `n_chains=C` the dense tau-leap kernel runs the chains as the
+    rows of one call (130 spans two row blocks). Each chain must equal, bit
+    for bit, the `n_chains=1` run on its chain key: the per-row scale
+    (per-chain schedule) and per-row bias (per-chain schedule, field noise)
+    take the same float operations as the shared ones.
+
+    The instance sits on multiples of 1/128 so that its energies are exact:
+    run()'s energy is a batched matvec under `n_chains > 1` and XLA
+    may add a batched matvec in another order than a single one."""
+    prob = _grid_exact_problem(denom=128)
+    n_steps = 12
+    key = jax.random.key(7)
+    faults = None
+    if variant == "per_chain_schedule":
+        schedule = np.asarray(
+            jnp.linspace(0.2, 2.5, C)[:, None] * jnp.linspace(1.0, 1.6, n_steps)[None, :]
+        )
+    else:
+        schedule = sampler_api.geometric(0.3, 3.0)
+    if variant == "faults":
+        stuck = np.zeros(prob.n, bool)
+        stuck[[2, 11, 30]] = True
+        faults = sampler_api.FaultModel(
+            stuck_mask=jnp.asarray(stuck),
+            stuck_values=jnp.asarray(np.where(np.arange(prob.n) % 2, 1.0, -1.0), jnp.float32),
+            field_noise_std=0.7,
+        )
+    kw = dict(n_steps=n_steps, sample_every=4, first_hit=-60.0, backend="pallas", faults=faults)
+    batched = run(prob, TauLeap(dt=0.25), key, n_chains=C, schedule=schedule, **kw)
+    # passlint: ignore[PASS001] run() splits the same key into its chain keys; the split redoes it
+    for c, k in enumerate(jax.random.split(key, C)):
+        sched_c = schedule[c] if variant == "per_chain_schedule" else schedule
+        one = run(prob, TauLeap(dt=0.25), k, schedule=sched_c, **kw)
+        for field in ("s", "samples", "energies", "t_hit"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(batched, field))[c], np.asarray(getattr(one, field)),
+                err_msg=f"chain {c}: {field}",
+            )
 
 
 def _masked_lattice(H=10, W=10, seed=0):
@@ -375,6 +423,32 @@ def test_unroll_event_blocks_bit_parity():
         run(prob, CTMC(), jax.random.key(0), n_steps=4, unroll=0)
     with pytest.raises(ValueError, match="unroll"):
         run(prob, CTMC(), jax.random.key(0), n_steps=4, unroll="fast")
+
+
+def test_tau_leap_pallas_steps_in_blocks_on_tpu(monkeypatch):
+    """run(unroll="auto") unrolls the scan TAU_LEAP_BLOCK_STEPS steps deep
+    where each step calls the compiled dense kernel (a TPU); elsewhere, on
+    the ref backend and on a lattice it keeps one step. The blocks change
+    no drawn number of the batched Pallas run."""
+    from repro.kernels import ops
+
+    prob = _grid_exact_problem(denom=128)
+    lat = problems.cal_problem(coupling=0.5)
+    assert TauLeap(backend="pallas").preferred_unroll(prob) == 1  # interpret mode
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert TauLeap(backend="pallas").preferred_unroll(prob) == sampler_api.TAU_LEAP_BLOCK_STEPS
+    assert TauLeap().preferred_unroll(prob) == 1
+    assert TauLeap(backend="pallas").preferred_unroll(lat) == 1
+    monkeypatch.undo()
+    kw = dict(n_steps=19, n_chains=3, sample_every=8, first_hit=-60.0, backend="pallas",
+              schedule=sampler_api.geometric(0.3, 3.0))
+    base = run(prob, TauLeap(dt=0.25), jax.random.key(3), unroll=1, **kw)
+    blocked = run(prob, TauLeap(dt=0.25), jax.random.key(3),
+                  unroll=sampler_api.TAU_LEAP_BLOCK_STEPS, **kw)
+    for field in ("s", "samples", "energies", "t_hit"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(base, field)), np.asarray(getattr(blocked, field)), err_msg=field
+        )
 
 
 def test_empty_result_dtypes_match_sampling_mode():

@@ -65,6 +65,15 @@ def test_tau_leap_step_compiles_n2048_b128(one_chip):
     _assert_kernel_compiles(tau_leap.tau_leap_step, args)
 
 
+def test_tau_leap_step_per_row_scale_bias_compiles_n2048_b128(one_chip):
+    """The per-row operands a batched run() passes: a (B,) scale, (B, N) bias."""
+    B, N = 128, 2048
+    args = _shapes(
+        one_chip, ((B, N), F32), ((N, N), I8), ((B, N), F32), ((B,), F32), ((B, N), F32), ((), F32)
+    )
+    _assert_kernel_compiles(tau_leap.tau_leap_step, args)
+
+
 def test_dense_field_compiles_n2048_b128(one_chip):
     B, N = 128, 2048
     args = _shapes(one_chip, ((B, N), I8), ((N, N), I8), ((N,), F32), ((), F32))
