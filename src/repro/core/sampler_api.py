@@ -25,9 +25,9 @@ Kernels implemented here, registered by name for config/benchmark selection:
     "chromatic_gibbs"   — exact parallel Gibbs on the king's-move lattice via
         the 4-coloring; one step = one sweep = 4 color phases.  Under
         `backend="pallas"` the whole sweep runs as ONE fused Pallas
-        `lattice_gibbs_sweep` call (lattice + weights VMEM-resident), the
-        chip's colored update groups; the ref path recomputes the stencil
-        field per color phase.
+        `lattice_gibbs_sweep` call (tiled by rows, each tile with its
+        weights VMEM-resident), the chip's colored update groups; the ref
+        path recomputes the stencil field per color phase.
     "colored_gibbs"     — chromatic Gibbs on ARBITRARY sparse graphs
         (`SparseIsing` + its greedy-coloring `color_masks`); one step = one
         sweep over the color classes with vectorized neighbor gathers.
@@ -403,8 +403,9 @@ class ChromaticGibbs:
     model time per step at per-neuron rate lambda0 is 1/lambda0.
 
     `backend="pallas"` routes the whole sweep through the fused Pallas
-    `lattice_gibbs_sweep` kernel (all 4 color phases with lattice + weights
-    resident in VMEM; compiled on TPU, interpreted elsewhere). The ref path
+    `lattice_gibbs_sweep` kernel (all 4 color phases on row tiles of the
+    lattice, each tile's weights resident in VMEM; compiled on TPU,
+    interpreted elsewhere). The ref path
     recomputes the full stencil field once per color phase in plain jnp.
     Both paths draw the same per-color uniforms from the same key split, so
     they agree bit-for-bit in interpret mode.

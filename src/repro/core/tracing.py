@@ -29,6 +29,9 @@ last program traced, which is the one running wherever one program is
 traced and then called (the benchmark's cells). `run()` on `n_chains`
 chains of dense tau-leap reads `n_chains` of `n_chains` rounded up to 128
 rows in one call per step, where it read 1 of 128 rows in `n_chains`.
+The king's-lattice sweep `lattice_gibbs_sweep` notes its chains the same
+way: `run()` maps one one-chain call per chain (vmap's grid axis), so
+`n_chains` chains read (1, 1, n_chains) — 128 calls per sweep at king384.
 """
 from __future__ import annotations
 

@@ -28,13 +28,15 @@ def on_tpu() -> bool:
 def lattice_gibbs_sweep(
     s, w, b, uniforms, colors, frozen, clamp_value, beta=None, mode: str = "auto", **kw
 ):
+    """Fused 4-color lattice sweep, tiled by rows; under `jax.vmap` one call
+    per mapped chain, noted in `tracing` (`lattice_gibbs.lattice_gibbs_rows`)."""
     if mode == "reference" or (mode == "auto" and not on_tpu()):
         cm = colors > 0.5
         fz = frozen > 0.5
         return _ref.lattice_gibbs_sweep_ref(s, w, b, uniforms, cm, fz, clamp_value, beta)
     # batch/block_batch divisibility is validated inside the kernel wrapper
     # (a readable ValueError at call/trace time, not a Pallas grid error)
-    return _lg.lattice_gibbs_sweep(
+    return _lg.lattice_gibbs_rows(
         s, w, b, uniforms, colors, frozen, clamp_value, beta, interpret=not on_tpu(), **kw
     )
 

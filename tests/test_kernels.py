@@ -16,8 +16,19 @@ def _rand_pm1(key, shape, dtype=jnp.float32):
     return (2 * jax.random.bernoulli(key, 0.5, shape) - 1).astype(dtype)
 
 
-@pytest.mark.parametrize("B,H,W", [(4, 16, 16), (8, 8, 8), (2, 32, 24), (16, 16, 16)])
-def test_lattice_gibbs_kernel_matches_ref(B, H, W):
+LATTICE_TILES = [(4, 16, 16, None), (8, 8, 8, None), (2, 32, 24, None), (16, 16, 16, None),
+                 (4, 16, 16, 8), (2, 32, 24, 8), (2, 32, 24, 16), (2, 40, 16, 16),
+                 (3, 30, 12, 8), (3, 30, 12, 16)]
+
+
+@pytest.mark.parametrize(
+    "B,H,W,block_rows", LATTICE_TILES,
+    ids=[f"{B}-{H}-{W}" + (f"-tile{t}" if t else "") for B, H, W, t in LATTICE_TILES],
+)
+def test_lattice_gibbs_kernel_matches_ref(B, H, W, block_rows):
+    """Bit-parity with the oracle at every tile height: one tile (None, the
+    VMEM budget's choice at these sizes), 8 and 16 rows; H = 40 and 30 leave
+    the last tile short."""
     k = jax.random.split(jax.random.key(0), 5)
     s = _rand_pm1(k[0], (B, H, W))
     w = jax.random.normal(k[1], (8, H, W)) * 0.5
@@ -31,9 +42,78 @@ def test_lattice_gibbs_kernel_matches_ref(B, H, W):
 
     # NOTE: w here is asymmetric (not a valid Ising problem) — fine for the
     # kernel-vs-oracle comparison, which is pure arithmetic.
-    got = lg.lattice_gibbs_sweep(s, w, b, u, colors, frozen, clampv, interpret=True, block_batch=2)
+    got = lg.lattice_gibbs_sweep(
+        s, w, b, u, colors, frozen, clampv, interpret=True, block_batch=1 if B == 3 else 2,
+        block_rows=block_rows,
+    )
     want = ref.lattice_gibbs_sweep_ref(s, w, b, u, colors_b, frozen_b, clampv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0, rtol=0)
+
+
+@pytest.mark.parametrize("block_rows", [8, 16, 24])
+def test_lattice_gibbs_tiles_with_faults_match_ref(block_rows):
+    """Chains mapped as `run()` maps them, with what faults give the kernel:
+    per-chain field noise in the bias, per-chain drops in the update masks,
+    dead sites (frozen, read as -1) and clamped sites (frozen at ±1). H = 40
+    gives 5 tiles of 8 rows, a short last tile of 16 and of 24 rows; tile
+    edges fall between an odd row above and an even row below, so both row
+    parities meet the halo."""
+    A, H, W = 3, 40, 16
+    k = jax.random.split(jax.random.key(21), 8)
+    s = _rand_pm1(k[0], (A, 1, H, W))
+    w = jax.random.normal(k[1], (8, H, W)) * 0.5
+    noisy_b = jax.random.normal(k[2], (H, W)) * 0.3 + 0.2 * jax.random.normal(k[3], (A, H, W))
+    u = jax.random.uniform(k[4], (A, 4, 1, H, W))
+    keep = jax.random.bernoulli(k[5], 0.8, (A, 1, H, W))
+    update = king_color_masks(H, W)[None] & keep  # (A, 4, H, W)
+    dead = jax.random.bernoulli(k[6], 0.1, (H, W))
+    clamped = jax.random.bernoulli(k[7], 0.1, (H, W)) & ~dead
+    frozen = dead | clamped
+    clampv = jnp.where(dead, -1.0, _rand_pm1(jax.random.key(22), (H, W)))
+    beta = jnp.asarray([0.4, 1.0, 2.5], jnp.float32)
+
+    def kernel(s, b, u, upd, bt):
+        return ops.lattice_gibbs_sweep(
+            s, w, b, u, upd.astype(jnp.float32), frozen.astype(jnp.float32), clampv, bt,
+            mode="kernel", block_batch=1, block_rows=block_rows,
+        )
+
+    def oracle(s, b, u, upd, bt):
+        return ref.lattice_gibbs_sweep_ref(s, w, b, u, upd, frozen, clampv, bt)
+
+    got = jax.vmap(kernel)(s, noisy_b, u, update, beta)
+    want = jax.vmap(oracle)(s, noisy_b, u, update, beta)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(jnp.sum(got != s)) > 0
+    np.testing.assert_array_equal(np.asarray(got)[:, 0][:, np.asarray(dead)], -1.0)
+
+
+def test_lattice_gibbs_tile_rows():
+    """Tile heights come from the VMEM budget: 64 rows at 384 x 384 with one
+    chain per block, fewer at 1024 wide; a height off the 8-row grid is refused."""
+    assert lg.tile_rows(384, 384, 1) == 64
+    assert lg.tile_rows(1024, 1024, 1) == 16
+    assert lg.tile_rows(128, 128, 8) == 64
+    assert lg.tile_rows(12, 12, 4) == 16
+    B, H, W = 1, 16, 16
+    with pytest.raises(ValueError, match="block_rows"):
+        lg.lattice_gibbs_sweep(
+            jnp.ones((B, H, W)), jnp.zeros((8, H, W)), jnp.zeros((H, W)),
+            jnp.zeros((4, B, H, W)), king_color_masks(H, W).astype(jnp.float32),
+            jnp.zeros((H, W)), jnp.ones((H, W)), block_rows=12,
+        )
+
+
+@pytest.mark.parametrize("chains", [1, 5])
+def test_lattice_gibbs_calls_noted(chains):
+    """`run()` on the lattice notes one one-chain call per chain and sweep."""
+    from repro.core import problems, sampler_api, tracing
+
+    problem = problems.get_problem("ferromagnet", 8).problem
+    jax.clear_caches()
+    sampler_api.run(problem, "chromatic_gibbs", jax.random.key(0), n_steps=2,
+                    n_chains=chains, backend="pallas")
+    assert tracing.row_occupancy("lattice_gibbs_sweep") == (1, 1, chains)
 
 
 @pytest.mark.parametrize(

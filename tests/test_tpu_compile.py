@@ -10,6 +10,7 @@ process at a time may load the TPU library, and every test worker imports
 this file. Each test asserts that the compiled program holds the kernel
 (`tpu_custom_call`), i.e. that nothing fell back to interpret mode.
 """
+import functools
 import os
 
 import jax
@@ -89,6 +90,36 @@ def test_lattice_gibbs_sweep_compiles_128x128_b8(one_chip):
         ((H, W), F32), ((H, W), F32), ((), F32),
     )
     _assert_kernel_compiles(lattice_gibbs.lattice_gibbs_sweep, args, block_batch=8)
+
+
+@pytest.mark.parametrize("H", [384, 1024])
+def test_lattice_gibbs_sweep_compiles_tiled_b1(one_chip, H):
+    """One chain at 384 x 384 (king384) and at 1024 x 1024: the row tiles
+    keep VMEM per program bounded whatever H is."""
+    B, W = 1, H
+    args = _shapes(
+        one_chip,
+        ((B, H, W), F32), ((8, H, W), F32), ((H, W), F32),
+        ((N_KING_COLORS, B, H, W), F32), ((N_KING_COLORS, H, W), F32),
+        ((H, W), F32), ((H, W), F32), ((), F32),
+    )
+    _assert_kernel_compiles(lattice_gibbs.lattice_gibbs_sweep, args, block_batch=1)
+
+
+def test_lattice_gibbs_rows_compiles_384x384_128_chains(one_chip):
+    """The call `run()` makes at king384: 128 one-chain sweeps mapped by
+    `jax.vmap`, each chain a grid axis of the tiled kernel, under one shared
+    beta schedule."""
+    A, H, W = 128, 384, 384
+    args = _shapes(
+        one_chip,
+        ((A, 1, H, W), F32), ((8, H, W), F32), ((H, W), F32),
+        ((A, N_KING_COLORS, 1, H, W), F32), ((N_KING_COLORS, H, W), F32),
+        ((H, W), F32), ((H, W), F32), ((), F32),
+    )
+    sweep = functools.partial(lattice_gibbs.lattice_gibbs_rows, interpret=False)
+    mapped = jax.jit(jax.vmap(sweep, in_axes=(0, None, None, 0, None, None, None, None)))
+    assert "tpu_custom_call" in mapped.lower(*args).compile().as_text()
 
 
 # n=4096 with max_deg 8 (a king's-graph degree) and a 4-coloring
