@@ -32,7 +32,7 @@ Kernels implemented here, registered by name for config/benchmark selection:
         (`SparseIsing` + its greedy-coloring `color_masks`); one step = one
         sweep over the color classes with vectorized neighbor gathers.
         Under `backend="pallas"` the sweep runs as ONE fused
-        `colored_gibbs_sweep` call (neighbor tables VMEM-resident).
+        `colored_gibbs_sweep` call, a batched run's chains in its lanes.
     "tau_leap"          — the PASS ASYNC model (lattice, dense, or sparse;
         ref path for non-dense): every
         neuron flips independently w.p. 1-exp(-dt*lambda_i) per step of
@@ -493,6 +493,15 @@ class ChromaticGibbs:
         return KernelState(s=s, t=state.t + 1.0 / self.lambda0, e=None, aux=())
 
 
+# run(unroll="auto") on the compiled sparse sweep: sweeps per scan
+# iteration. A sweep of 64 chains at n = 4096 is about 185 µs, and one sweep
+# per iteration adds the scan's own copies to each: about 100 device
+# operations a sweep against about 41 in blocks of 10 (the v5e compiler's
+# program). On a TPU v5e the blocks ran 5% more jobs in a traced window and
+# kept twice as much of it in the profiler's trace (about 5M operations).
+COLORED_BLOCK_SWEEPS = 10
+
+
 @register_kernel("colored_gibbs")
 @partial(
     jax.tree_util.register_dataclass,
@@ -510,8 +519,11 @@ class ColoredGibbs:
     1/lambda0 per sweep, like `chromatic_gibbs`).
 
     `backend="pallas"` routes the whole sweep through the fused
-    `colored_gibbs_sweep` kernel (neighbor tables VMEM-resident, all color
-    phases in one pallas_call; compiled on TPU, interpreted elsewhere). The
+    `colored_gibbs_sweep` kernel (sites in rows and chains in lanes, each
+    neighbour's row loaded by its index, all color phases in one
+    pallas_call; compiled on TPU, interpreted elsewhere). Its walk over the
+    color classes (`sparse_gather.sweep_plan`) is built once per run, in
+    `init`, and carried in the state. The
     ref path recomputes the gathered fields once per color phase in plain
     jnp. Both paths draw the same per-color uniforms from the same key
     split and evaluate the identical gather+reduce expression, so they
@@ -522,6 +534,14 @@ class ColoredGibbs:
 
     lambda0: float = 1.0
     backend: str = "ref"  # "ref" | "pallas"
+
+    def preferred_unroll(self, problem) -> int:
+        """Sweeps per scan iteration for run(unroll="auto"): a block of
+        `COLORED_BLOCK_SWEEPS` where every sweep is a call of the compiled
+        kernel (backend "pallas" on a TPU), 1 elsewhere."""
+        from repro.kernels import ops
+
+        return COLORED_BLOCK_SWEEPS if self.backend == "pallas" and ops.on_tpu() else 1
 
     def init(self, problem: SparseIsing, key, s0=None, faults=None) -> KernelState:
         """Initial state; requires the problem's color_masks."""
@@ -535,7 +555,22 @@ class ColoredGibbs:
             s0 = random_init(key, state_shape(problem))
         if faults is not None:
             s0 = faults.apply_stuck(s0)
-        return KernelState(s=s0, t=jnp.asarray(0.0, jnp.float32), e=None, aux=())
+        aux = ()
+        if self.backend == "pallas":
+            from repro.kernels import sparse_gather
+
+            # the kernel's walk over the color classes, once per run
+            aux = sparse_gather.sweep_plan(
+                problem.nbr_idx, problem.nbr_w, problem.b, self._masks(problem, faults)
+            )
+        return KernelState(s=s0, t=jnp.asarray(0.0, jnp.float32), e=None, aux=aux)
+
+    @staticmethod
+    def _masks(problem: SparseIsing, faults) -> jax.Array:
+        """The color masks with stuck sites taken out of every class."""
+        stuck = faults.stuck_flat() if faults is not None else None
+        masks = problem.color_masks  # (C, n) bool
+        return masks if stuck is None else masks & ~stuck
 
     def step(self, problem: SparseIsing, state, key, beta, faults=None) -> KernelState:
         """One sweep over the graph's color classes.
@@ -543,7 +578,7 @@ class ColoredGibbs:
         Faults fold into the color masks (stuck/dropped sites leave their
         color class for this sweep) and into the bias (one per-sweep field-
         noise draw shared by all phases), identically on both backends."""
-        masks = problem.color_masks  # (C, n) bool
+        masks = self._masks(problem, faults)  # (C, n) & ~stuck (n,), per color
         s = state.s
         eta = keep = None
         if faults is not None and (faults.noisy or faults.drops):
@@ -552,9 +587,6 @@ class ColoredGibbs:
                 eta = faults.field_noise(k_noise, s.shape)
             if faults.drops:
                 keep = faults.keep_mask(k_drop, s.shape)
-        stuck = faults.stuck_flat() if faults is not None else None
-        if stuck is not None:
-            masks = masks & ~stuck  # (C, n) & (n,) broadcasts per color
         if keep is not None:
             masks = masks & keep
         keys = jax.random.split(key, masks.shape[0])
@@ -564,15 +596,18 @@ class ColoredGibbs:
             u = jnp.stack(
                 [jax.random.uniform(keys[c], s.shape) for c in range(masks.shape[0])]
             )
+            # The plan holds the shared bias and classes; noise and drops
+            # differ per sweep and go in per chain, the chain's own row.
             s = ops.colored_gibbs_sweep(
                 s[None],
                 problem.nbr_idx,
                 problem.nbr_w,
-                problem.b if eta is None else problem.b + eta,
+                problem.b if eta is None else (problem.b + eta)[None],
                 u[:, None],
-                masks.astype(s.dtype),
+                masks.astype(s.dtype) if keep is None else masks[:, None].astype(s.dtype),
                 beta=beta,
                 mode="kernel",
+                plan=state.aux or None,
             )[0]
         else:
             prob = (
@@ -585,7 +620,7 @@ class ColoredGibbs:
                 u = jax.random.uniform(keys[c], s.shape)
                 proposal = jnp.where(u < p_up, 1.0, -1.0).astype(s.dtype)
                 s = jnp.where(masks[c], proposal, s)
-        return KernelState(s=s, t=state.t + 1.0 / self.lambda0, e=None, aux=())
+        return KernelState(s=s, t=state.t + 1.0 / self.lambda0, e=None, aux=state.aux)
 
 
 # run(unroll="auto") on the compiled dense tau-leap kernel: steps per scan

@@ -32,6 +32,8 @@ rows in one call per step, where it read 1 of 128 rows in `n_chains`.
 The king's-lattice sweep `lattice_gibbs_sweep` notes its chains the same
 way: `run()` maps one one-chain call per chain (vmap's grid axis), so
 `n_chains` chains read (1, 1, n_chains) — 128 calls per sweep at king384.
+The sparse sweep `colored_gibbs_sweep` holds chains in its lanes: `run()`
+on `n_chains` chains reads (n_chains, n_chains rounded up to 128, 1).
 """
 from __future__ import annotations
 

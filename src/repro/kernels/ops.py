@@ -49,14 +49,20 @@ def sparse_fields(s, nbr_idx, nbr_w, b, mode: str = "auto", **kw):
     return _sg.sparse_fields(s, nbr_idx, nbr_w, b, interpret=not on_tpu(), **kw)
 
 
-def colored_gibbs_sweep(s, nbr_idx, nbr_w, b, uniforms, masks, beta=None, mode: str = "auto", **kw):
+def colored_gibbs_sweep(
+    s, nbr_idx, nbr_w, b, uniforms, masks, beta=None, mode: str = "auto", plan=None, **kw
+):
+    """Fused chromatic sweep of a sparse graph, chains in the kernel's lanes;
+    under `jax.vmap` the mapped calls become one call of all the chains
+    (`sparse_gather.colored_gibbs_lanes`). `plan`, when given, is
+    `sparse_gather.sweep_plan(nbr_idx, nbr_w, b, masks)`, built once per problem."""
     if mode == "reference" or (mode == "auto" and not on_tpu()):
         return _ref.colored_gibbs_sweep_ref(s, nbr_idx, nbr_w, b, uniforms, masks > 0.5, beta)
     from repro.kernels import sparse_gather as _sg
 
-    # batch/block_batch divisibility is validated inside the kernel wrapper
-    return _sg.colored_gibbs_sweep(
-        s, nbr_idx, nbr_w, b, uniforms, masks, beta, interpret=not on_tpu(), **kw
+    # block_lanes is validated inside the kernel wrapper (a readable ValueError)
+    return _sg.colored_gibbs_lanes(
+        s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan, interpret=not on_tpu(), **kw
     )
 
 

@@ -73,8 +73,9 @@ def colored_gibbs_sweep_ref(
     """One full chromatic Gibbs sweep on a sparse graph at inverse
     temperature beta.
 
-    s: (B,n) ±1; uniforms: (C,B,n); color_masks: (C,n) bool independent-set
-    masks; beta: () scalar (None -> 1.0).
+    s: (B,n) ±1; b: (n,), or (B,n) per chain; uniforms: (C,B,n);
+    color_masks: (C,n) bool independent-set masks, or (C,B,n) per chain;
+    beta: () scalar (None -> 1.0).
     """
     if beta is None:
         beta = jnp.ones((), jnp.float32)
@@ -84,7 +85,7 @@ def colored_gibbs_sweep_ref(
         # multiply order matches glauber.prob_up(beta*h): sigma(-2*(beta*h))
         p_up = jax.nn.sigmoid(-2.0 * (beta * h))
         proposal = jnp.where(uniforms[c] < p_up, 1.0, -1.0).astype(s.dtype)
-        s = jnp.where(color_masks[c][None], proposal, s)
+        s = jnp.where(color_masks[c], proposal, s)
     return s
 
 

@@ -1,4 +1,6 @@
 """Per-kernel allclose sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -344,7 +346,7 @@ def test_colored_gibbs_kernel_matches_ref_beta(beta):
     beta_arr = None if beta is None else jnp.asarray(beta, jnp.float32)
     got = sg.colored_gibbs_sweep(
         s, sp.nbr_idx, sp.nbr_w, sp.b, u, sp.color_masks.astype(jnp.float32),
-        beta_arr, interpret=True, block_batch=2,
+        beta_arr, interpret=True,
     )
     want = ref.colored_gibbs_sweep_ref(
         s, sp.nbr_idx, sp.nbr_w, sp.b, u, sp.color_masks, beta_arr
@@ -353,24 +355,123 @@ def test_colored_gibbs_kernel_matches_ref_beta(beta):
 
 
 def test_ops_sparse_eager_block_batch_validation():
-    """mode='kernel' with a batch the block doesn't divide must fail fast
-    with a readable ValueError, not an opaque Pallas grid error."""
+    """mode='kernel' with a block the kernel cannot take must fail fast with
+    a readable ValueError, not an opaque Pallas grid error: the colored
+    sweep's lane block (its chains) must be a multiple of 128 lanes."""
     sp = _rand_sparse_tables(jax.random.key(25), 12)
     C = sp.color_masks.shape[0]
     s = jnp.ones((6, 12))
     u = jnp.zeros((C, 6, 12))
     masks = sp.color_masks.astype(jnp.float32)
-    with pytest.raises(ValueError, match="block_batch"):
+    with pytest.raises(ValueError, match="block_lanes"):
         ops.colored_gibbs_sweep(s, sp.nbr_idx, sp.nbr_w, sp.b, u, masks,
-                                mode="kernel", block_batch=4)
+                                mode="kernel", block_lanes=4)
     with pytest.raises(ValueError, match="block_batch"):
         ops.sparse_fields(s, sp.nbr_idx, sp.nbr_w, sp.b, mode="kernel", block_batch=5)
-    # a dividing block is fine, and matches the reference mode bit-for-bit
+    # a whole lane block is fine, and matches the reference mode bit-for-bit
     out = ops.colored_gibbs_sweep(s, sp.nbr_idx, sp.nbr_w, sp.b, u, masks,
-                                  mode="kernel", block_batch=3)
+                                  mode="kernel", block_lanes=128)
     want = ops.colored_gibbs_sweep(s, sp.nbr_idx, sp.nbr_w, sp.b, u, masks,
                                    mode="reference")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def _irregular_graph(n, seed):
+    """A sparse graph of degrees 1 to 5 (so most sites have padded slots),
+    random ±weights and biases, greedily colored."""
+    from repro.core.sparse import SparseIsing
+
+    rng = np.random.default_rng(seed)
+    edges = {(i, (i + 1) % n) for i in range(n)}
+    while len(edges) < 2 * n:
+        i, j = sorted(rng.choice(n, 2, replace=False))
+        edges.add((int(i), int(j)))
+    deg = np.zeros(n, int)
+    kept = []
+    for i, j in sorted(edges):
+        if deg[i] < 5 and deg[j] < 5:
+            kept.append((i, j, float(rng.normal())))
+            deg[i] += 1
+            deg[j] += 1
+    sp = SparseIsing.from_edges(n, kept)
+    return dataclasses.replace(sp, b=jnp.asarray(rng.normal(size=n) * 0.3, jnp.float32))
+
+
+# chains: 1, 3, 64 and 130 (two lane blocks); n: below a block of 8, not a
+# multiple of 8, and not of 128
+SWEEP_SHAPES = [(1, 5), (3, 37), (64, 29), (130, 133)]
+
+
+@pytest.mark.parametrize("B,n", SWEEP_SHAPES, ids=[f"B{B}-n{n}" for B, n in SWEEP_SHAPES])
+def test_colored_gibbs_sweep_shapes_match_ref(B, n):
+    """Chains in lanes: bit-parity with the oracle for any chain count and
+    any n, at irregular degree with padded slots."""
+    from repro.kernels import sparse_gather as sg
+
+    sp = _irregular_graph(n, seed=n)
+    assert int(np.min(np.asarray(sp.deg))) < sp.max_deg  # padded slots occur
+    C = sp.color_masks.shape[0]
+    s = _rand_pm1(jax.random.key(B), (B, n))
+    u = jax.random.uniform(jax.random.key(n), (C, B, n))
+    beta = jnp.asarray(0.8, jnp.float32)
+    got = sg.colored_gibbs_sweep(
+        s, sp.nbr_idx, sp.nbr_w, sp.b, u, sp.color_masks.astype(jnp.float32), beta,
+        interpret=True,
+    )
+    want = ref.colored_gibbs_sweep_ref(s, sp.nbr_idx, sp.nbr_w, sp.b, u, sp.color_masks, beta)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert 0 < float(jnp.mean(got != s)) < 1  # the sweep did flip spins
+
+
+VMAP_CASES = ["shared_beta", "chain_beta", "chain_bias", "chain_masks"]
+
+
+@pytest.mark.parametrize("case", VMAP_CASES)
+def test_colored_gibbs_vmap_matches_ref(case):
+    """Under `jax.vmap` of one-chain calls, as `run()` makes them, the A
+    calls become one call of A lanes; a per-chain beta, bias or update mask
+    becomes a per-lane operand. Each chain's result equals the oracle's."""
+    from repro.core import tracing
+
+    A, n = 5, 23
+    sp = _irregular_graph(n, seed=3)
+    C = sp.color_masks.shape[0]
+    keys = jax.random.split(jax.random.key(7), 4)
+    s = _rand_pm1(keys[0], (A, 1, n))
+    u = jax.random.uniform(keys[1], (A, C, 1, n))
+    beta = jnp.asarray(0.6, jnp.float32)
+    b = sp.b
+    masks = sp.color_masks
+    axes = [0, None, None, None, 0, None, None]
+    if case == "chain_beta":
+        beta, axes[6] = jnp.linspace(0.2, 2.0, A, dtype=jnp.float32), 0
+    if case == "chain_bias":
+        b = sp.b + 0.5 * jax.random.normal(keys[2], (A, 1, n))
+        axes[3] = 0
+    if case == "chain_masks":  # drops: each chain keeps its own sites
+        keep = jax.random.bernoulli(keys[3], 0.7, (A, 1, n))
+        masks, axes[5] = sp.color_masks[None, :, None] & keep[:, None], 0
+    kernel = lambda *a: ops.colored_gibbs_sweep(*a, mode="kernel")
+    oracle = lambda *a: ops.colored_gibbs_sweep(*a, mode="reference")
+    args = (s, sp.nbr_idx, sp.nbr_w, b, u, masks.astype(jnp.float32), beta)
+    got = jax.vmap(kernel, in_axes=axes)(*args)
+    assert tracing.row_occupancy("colored_gibbs_sweep") == tracing.RowOccupancy(A, 128, 1)
+    want = jax.vmap(oracle, in_axes=axes)(*args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("A", [2, 6])
+def test_colored_gibbs_row_occupancy(A):
+    """run() on A chains puts them in the lanes of ONE kernel call per
+    sweep: A of 128 lanes, where each chain used to be a call of its own.
+    The note is made while the program is traced, so each case runs a
+    shape no other test runs."""
+    from repro.core import sampler_api, tracing
+
+    sp = _irregular_graph(19, seed=A)
+    sampler_api.run(sp, sampler_api.ColoredGibbs(), jax.random.key(0), n_steps=3,
+                    n_chains=A, backend="pallas")
+    assert tracing.row_occupancy("colored_gibbs_sweep") == tracing.RowOccupancy(A, 128, 1)
 
 
 def test_ops_auto_uses_reference_on_cpu():

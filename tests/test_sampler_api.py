@@ -9,6 +9,7 @@ from repro.core import ctmc, ising, problems, sampler_api, samplers
 from repro.core.sampler_api import (
     CTMC,
     ChromaticGibbs,
+    ColoredGibbs,
     RandomScanGibbs,
     TauLeap,
     constant,
@@ -445,6 +446,32 @@ def test_tau_leap_pallas_steps_in_blocks_on_tpu(monkeypatch):
     base = run(prob, TauLeap(dt=0.25), jax.random.key(3), unroll=1, **kw)
     blocked = run(prob, TauLeap(dt=0.25), jax.random.key(3),
                   unroll=sampler_api.TAU_LEAP_BLOCK_STEPS, **kw)
+    for field in ("s", "samples", "energies", "t_hit"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(base, field)), np.asarray(getattr(blocked, field)), err_msg=field
+        )
+
+
+def test_colored_gibbs_pallas_sweeps_in_blocks_on_tpu(monkeypatch):
+    """run(unroll="auto") unrolls the scan COLORED_BLOCK_SWEEPS sweeps deep
+    where each sweep calls the compiled sparse kernel (a TPU); elsewhere
+    and on the ref backend it keeps one sweep. The blocks change no drawn
+    number of the batched Pallas run."""
+    from repro.kernels import ops
+
+    prob = problems.random_3regular_maxcut(16, seed=1)
+    assert ColoredGibbs(backend="pallas").preferred_unroll(prob) == 1  # interpret mode
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert ColoredGibbs(backend="pallas").preferred_unroll(prob) == (
+        sampler_api.COLORED_BLOCK_SWEEPS
+    )
+    assert ColoredGibbs().preferred_unroll(prob) == 1
+    monkeypatch.undo()
+    kw = dict(n_steps=13, n_chains=3, sample_every=5, first_hit=-14.0, backend="pallas",
+              schedule=sampler_api.geometric(0.3, 3.0))
+    base = run(prob, ColoredGibbs(), jax.random.key(3), unroll=1, **kw)
+    blocked = run(prob, ColoredGibbs(), jax.random.key(3),
+                  unroll=sampler_api.COLORED_BLOCK_SWEEPS, **kw)
     for field in ("s", "samples", "energies", "t_hit"):
         np.testing.assert_array_equal(
             np.asarray(getattr(base, field)), np.asarray(getattr(blocked, field)), err_msg=field

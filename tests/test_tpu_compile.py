@@ -133,7 +133,30 @@ def test_colored_gibbs_sweep_compiles_n4096_deg8_b8(one_chip):
         ((B, n), F32), ((n, md), I32), ((n, md), F32), ((n,), F32),
         ((C, B, n), F32), ((C, n), F32), ((), F32),
     )
-    _assert_kernel_compiles(sparse_gather.colored_gibbs_sweep, args, block_batch=8)
+    _assert_kernel_compiles(sparse_gather.colored_gibbs_sweep, args)
+
+
+@pytest.mark.parametrize("A,chain_beta", [(64, False), (128, False), (128, True)])
+def test_colored_gibbs_lanes_compiles_n4096_deg3(one_chip, A, chain_beta):
+    """The call `run()` makes at maxcut3r4096: A one-chain sweeps mapped by
+    `jax.vmap` become one call with the A chains in its lanes, under one
+    shared beta schedule (or one per chain, a (1, A) VMEM row) and the plan
+    built once per run."""
+    n, md, C = SPARSE_N, 3, SPARSE_C
+    args = _shapes(
+        one_chip,
+        ((A, 1, n), F32), ((n, md), I32), ((n, md), F32), ((n,), F32),
+        ((A, C, 1, n), F32), ((C, n), F32), ((A,) if chain_beta else (), F32),
+    )
+
+    @jax.jit
+    def mapped(s, nbr_idx, nbr_w, b, u, masks, beta):
+        plan = sparse_gather.sweep_plan(nbr_idx, nbr_w, b, masks)
+        sweep = functools.partial(sparse_gather.colored_gibbs_lanes, interpret=False)
+        axes = (0, None, None, None, 0, None, 0 if chain_beta else None, None)
+        return jax.vmap(sweep, in_axes=axes)(s, nbr_idx, nbr_w, b, u, masks, beta, plan)
+
+    assert "tpu_custom_call" in mapped.lower(*args).compile().as_text()
 
 
 def test_sparse_fields_compiles_n4096_deg8_b8(one_chip):
