@@ -137,8 +137,8 @@ def sparse_oracle(ph: Phase, key, beta=1.0):
 
 
 def _kernel_call(op, args, what):
-    """Compile op(mode="kernel") on its own, check it holds the kernel, run it."""
-    compiled = jax.jit(lambda *a: op(*a, mode="kernel")).lower(*args).compile()
+    """Compile op on its own, check it holds the kernel, run it."""
+    compiled = jax.jit(op).lower(*args).compile()
     require_kernel(compiled, what)
     return compiled(*args)
 

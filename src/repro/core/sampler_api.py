@@ -59,6 +59,9 @@ Schedule object (`constant` / `linear` / `geometric`).  `backend` is
 kernel/problem combination) with no Pallas path raises ValueError instead
 of silently running the ref path; "auto" picks the best backend the kernel
 supports on this platform (compiled Pallas on TPU, reference elsewhere).
+It is the one switch: a kernel's Pallas branch calls its kernel through
+`repro.kernels.ops`, which always runs the Pallas kernel (interpreted off
+a TPU).
 The legacy entry points in `samplers` / `annealing` / `ctmc` are thin
 deprecated wrappers over this driver and reproduce their historical
 outputs bit-for-bit at beta=1.
@@ -79,6 +82,7 @@ from repro.core.diagnostics import RunDiagnostics  # noqa: F401  (re-export)
 from repro.core.faults import FaultModel  # noqa: F401  (re-export)
 from repro.core.ising import DenseIsing, LatticeIsing, king_color_masks
 from repro.core.sparse import SparseIsing
+from repro.kernels import ops
 
 
 class NonFiniteEnergyError(ValueError):
@@ -286,6 +290,26 @@ def _tau_leap_flip(s, h, key, dt, trim, frozen, keep=None):
     return jnp.where(flips, -s, s)
 
 
+def _fault_draws(key, faults, shape):
+    """A sweep's or step's fault draws: `(key, eta, keep)`, the key left for
+    the step's own draws, field noise or None and a keep mask or None. The
+    key splits in 3 only where faults are noisy or drop updates."""
+    eta = keep = None
+    if faults is not None and (faults.noisy or faults.drops):
+        key, k_noise, k_drop = jax.random.split(key, 3)
+        if faults.noisy:
+            eta = faults.field_noise(k_noise, shape)
+        if faults.drops:
+            keep = faults.keep_mask(k_drop, shape)
+    return key, eta, keep
+
+
+def _color_uniforms(keys, shape):
+    """The (C, *shape) uniforms of a fused sweep: color c's from `keys[c]`,
+    as the ref path draws them phase by phase."""
+    return jnp.stack([jax.random.uniform(keys[c], shape) for c in range(keys.shape[0])])
+
+
 def resolve_schedule(
     schedule: ScheduleLike, n_steps: int, n_chains: Optional[int] = None
 ) -> jax.Array:
@@ -448,21 +472,11 @@ class ChromaticGibbs:
         colors = king_color_masks(H, W)
         frozen = problem.frozen_mask
         s = state.s
-        eta = keep = None
-        if faults is not None and (faults.noisy or faults.drops):
-            key, k_noise, k_drop = jax.random.split(key, 3)
-            if faults.noisy:
-                eta = faults.field_noise(k_noise, s.shape)
-            if faults.drops:
-                keep = faults.keep_mask(k_drop, s.shape)
+        key, eta, keep = _fault_draws(key, faults, s.shape)
         keys = jax.random.split(key, colors.shape[0])
         if self.backend == "pallas":
             # trim is rejected in init(), which every driver path runs first
-            from repro.kernels import ops
-
-            u = jnp.stack(
-                [jax.random.uniform(keys[c], s.shape) for c in range(colors.shape[0])]
-            )
+            u = _color_uniforms(keys, s.shape)
             update = colors if keep is None else colors & keep
             s = ops.lattice_gibbs_sweep(
                 s[None],
@@ -473,7 +487,6 @@ class ChromaticGibbs:
                 frozen.astype(s.dtype),
                 problem.frozen_values.astype(s.dtype),
                 beta=beta,
-                mode="kernel",
             )[0]
         else:
             prob = (
@@ -522,7 +535,7 @@ class ColoredGibbs:
     `colored_gibbs_sweep` kernel (sites in rows and chains in lanes, each
     neighbour's row loaded by its index, all color phases in one
     pallas_call; compiled on TPU, interpreted elsewhere). Its walk over the
-    color classes (`sparse_gather.sweep_plan`) is built once per run, in
+    color classes (`ops.sweep_plan`) is built once per run, in
     `init`, and carried in the state. The
     ref path recomputes the gathered fields once per color phase in plain
     jnp. Both paths draw the same per-color uniforms from the same key
@@ -539,8 +552,6 @@ class ColoredGibbs:
         """Sweeps per scan iteration for run(unroll="auto"): a block of
         `COLORED_BLOCK_SWEEPS` where every sweep is a call of the compiled
         kernel (backend "pallas" on a TPU), 1 elsewhere."""
-        from repro.kernels import ops
-
         return COLORED_BLOCK_SWEEPS if self.backend == "pallas" and ops.on_tpu() else 1
 
     def init(self, problem: SparseIsing, key, s0=None, faults=None) -> KernelState:
@@ -557,10 +568,8 @@ class ColoredGibbs:
             s0 = faults.apply_stuck(s0)
         aux = ()
         if self.backend == "pallas":
-            from repro.kernels import sparse_gather
-
             # the kernel's walk over the color classes, once per run
-            aux = sparse_gather.sweep_plan(
+            aux = ops.sweep_plan(
                 problem.nbr_idx, problem.nbr_w, problem.b, self._masks(problem, faults)
             )
         return KernelState(s=s0, t=jnp.asarray(0.0, jnp.float32), e=None, aux=aux)
@@ -580,22 +589,12 @@ class ColoredGibbs:
         noise draw shared by all phases), identically on both backends."""
         masks = self._masks(problem, faults)  # (C, n) & ~stuck (n,), per color
         s = state.s
-        eta = keep = None
-        if faults is not None and (faults.noisy or faults.drops):
-            key, k_noise, k_drop = jax.random.split(key, 3)
-            if faults.noisy:
-                eta = faults.field_noise(k_noise, s.shape)
-            if faults.drops:
-                keep = faults.keep_mask(k_drop, s.shape)
+        key, eta, keep = _fault_draws(key, faults, s.shape)
         if keep is not None:
             masks = masks & keep
         keys = jax.random.split(key, masks.shape[0])
         if self.backend == "pallas":
-            from repro.kernels import ops
-
-            u = jnp.stack(
-                [jax.random.uniform(keys[c], s.shape) for c in range(masks.shape[0])]
-            )
+            u = _color_uniforms(keys, s.shape)
             # The plan holds the shared bias and classes; noise and drops
             # differ per sweep and go in per chain, the chain's own row.
             s = ops.colored_gibbs_sweep(
@@ -606,7 +605,6 @@ class ColoredGibbs:
                 u[:, None],
                 masks.astype(s.dtype) if keep is None else masks[:, None].astype(s.dtype),
                 beta=beta,
-                mode="kernel",
                 plan=state.aux or None,
             )[0]
         else:
@@ -670,8 +668,6 @@ class TauLeap:
         """Steps per scan iteration for run(unroll="auto"): a block of
         `TAU_LEAP_BLOCK_STEPS` where every step is a call of the compiled
         dense kernel (backend "pallas" on a TPU), 1 elsewhere."""
-        from repro.kernels import ops
-
         if self.backend == "pallas" and isinstance(problem, DenseIsing) and ops.on_tpu():
             return TAU_LEAP_BLOCK_STEPS
         return 1
@@ -701,8 +697,6 @@ class TauLeap:
         elif self.backend == "pallas":
             if self.trim is not None:
                 raise NotImplementedError("pallas tau-leap does not support trims")
-            from repro.kernels import ops
-
             aux = ops.quantize_dense(problem.J)  # (j_i8, scale), once per run
         return KernelState(s=s0, t=jnp.asarray(0.0, jnp.float32), e=None, aux=aux)
 
@@ -717,13 +711,7 @@ class TauLeap:
         oblivious. Lattice stuck sites arrive pre-absorbed into the clamp
         masks via `FaultModel.bind`."""
         s = state.s
-        eta = keep = None
-        if faults is not None and (faults.noisy or faults.drops):
-            key, k_noise, k_drop = jax.random.split(key, 3)
-            if faults.noisy:
-                eta = faults.field_noise(k_noise, s.shape)
-            if faults.drops:
-                keep = faults.keep_mask(k_drop, s.shape)
+        key, eta, keep = _fault_draws(key, faults, s.shape)
         stuck = faults.stuck_flat() if faults is not None else None
         if isinstance(problem, LatticeIsing):
             h = problem.local_fields(s)
@@ -734,8 +722,6 @@ class TauLeap:
             )
             s = problem.apply_clamps(s)
         elif self.backend == "pallas":
-            from repro.kernels import ops
-
             j_i8, scale = state.aux
             u = jax.random.uniform(key, s.shape)
             if stuck is not None or keep is not None:
@@ -752,7 +738,6 @@ class TauLeap:
                 beta * scale,
                 u[None, :],
                 jnp.asarray(self.dt, jnp.float32),
-                mode="kernel",
             )[0]
         else:
             h = problem.local_fields(s)
@@ -1112,8 +1097,6 @@ def _resolve_backend(backend: Optional[str], kernel=None, problem=None) -> Optio
         raise ValueError(f"backend must be 'ref' | 'pallas' | 'auto', got {backend!r}")
     supported = ("ref", "pallas") if kernel is None else kernel_backends(kernel, problem)
     if backend == "auto":
-        from repro.kernels import ops
-
         return "pallas" if ops.on_tpu() and "pallas" in supported else "ref"
     if backend not in supported:
         name = getattr(kernel, "name", type(kernel).__name__)

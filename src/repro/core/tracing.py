@@ -20,10 +20,11 @@ calls are kept. A `run()` traced by an outer transformation (the jitted
 tempering loop) times tracing, not running, and leaves no span and no
 record. A call that raises leaves no record.
 
-Row occupancy. A Pallas kernel whose rows are chains notes, each time a
-program calling it is traced, the rows that carry a chain per call, the
-rows the MXU processes per call (those padded to its row tile) and the
-calls per step; `row_occupancy(kernel)` reads the last note. A program
+Row occupancy. The one rule by which `run()`'s chains reach a Pallas
+kernel (`repro.kernels.chains.batched`) notes, each time a program calling
+the kernel is traced, the rows that carry a chain per call, the rows the
+kernel processes per call (those padded to its tile) and the calls per
+step; `row_occupancy(kernel)` reads the last note. A program
 served from JAX's caches is not traced again, so the note is that of the
 last program traced, which is the one running wherever one program is
 traced and then called (the benchmark's cells). `run()` on `n_chains`

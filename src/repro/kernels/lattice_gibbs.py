@@ -32,8 +32,7 @@ retracing: p_up = sigma(-2*beta*h).
 
 `run()` sweeps each chain as the one-chain batch `s[None]` and maps chains
 with `jax.vmap`; `lattice_gibbs_rows` maps them as vmap's default rule
-does, one call per chain as a grid axis, and notes the calls of each trace
-in `repro.core.tracing.row_occupancy`.
+does, one call per chain as a grid axis (`repro.kernels.chains`).
 """
 from __future__ import annotations
 
@@ -44,8 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import tracing
 from repro.core.ising import KING_OFFSETS, N_KING_COLORS
+from repro.kernels import chains
 
 # Rows of a halo block: the sublane tile of 32-bit values. Tile heights are
 # multiples of it, so every block starts on an aligned row.
@@ -222,34 +221,7 @@ def lattice_gibbs_sweep(
     return out[:, :H]
 
 
-def _noted_sweep(s, w, b, uniforms, colors, frozen, clamp_value, beta, *, calls, **kw):
-    """`lattice_gibbs_sweep`, noting its chains in `tracing` while it is traced."""
-    rows = s.shape[0]
-    tracing.note_rows("lattice_gibbs_sweep", rows, rows, calls)
-    return lattice_gibbs_sweep(s, w, b, uniforms, colors, frozen, clamp_value, beta, **kw)
-
-
-def lattice_gibbs_rows(s, w, b, uniforms, colors, frozen, clamp_value, beta=None, **kw):
-    """`lattice_gibbs_sweep` (keyword arguments go to it), with a `jax.vmap`
-    rule that notes its calls.
-
-    Under `jax.vmap` — `run()` maps each chain's scan over chains, and a
-    chain sweeps as the one-chain batch `s[None]` — the A mapped calls stay
-    A calls, one per mapped element as a grid axis, exactly as vmap's
-    default rule maps a `pallas_call`. Each trace notes the chains per call
-    (a batch is never padded, so the padded rows are the same) and the
-    calls per sweep in `tracing.row_occupancy`.
-    """
-    beta = jnp.ones((), jnp.float32) if beta is None else jnp.asarray(beta, jnp.float32)
-
-    @jax.custom_batching.custom_vmap
-    def sweep(*args):
-        return _noted_sweep(*args, calls=1, **kw)
-
-    @sweep.def_vmap
-    def per_chain(A, in_batched, *args):
-        call = functools.partial(_noted_sweep, calls=A, **kw)
-        axes = [0 if m else None for m in in_batched]
-        return jax.vmap(call, in_axes=axes)(*args), True
-
-    return sweep(s, w, b, uniforms, colors, frozen, clamp_value, beta)
+# `lattice_gibbs_sweep` with `run()`'s chains mapped as vmap's default rule
+# maps them: one one-chain call per chain, as a grid axis. It has no fold
+# yet; the chains rule (`repro.kernels.chains`) notes its calls.
+lattice_gibbs_rows = chains.batched(lattice_gibbs_sweep, "lattice_gibbs_sweep")

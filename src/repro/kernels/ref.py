@@ -1,7 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the ground truth for tests (interpret-mode allclose sweeps) and
-the CPU fallback used by ops.py when no TPU is present.
+for the on-chip checks of `chip_smoke.py`. `dense_field_ref` and
+`sparse_fields_ref` are the fields the tau-leap and colored-sweep oracles
+are built on.
 """
 from __future__ import annotations
 

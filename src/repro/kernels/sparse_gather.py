@@ -1,15 +1,10 @@
 """Pallas kernels for sparse (neighbor-list) Ising problems.
 
-Two kernels over the padded `SparseIsing` layout (`repro.core.sparse`):
-
-  sparse_fields        — local fields h = gather(s, nbr_idx) . nbr_w + b,
-                         the O(n * max_deg) analogue of the dense int8
-                         matmul engine.
-  colored_gibbs_sweep  — one full chromatic Gibbs sweep fused over all
-                         color phases, the arbitrary-graph generalization
-                         of `lattice_gibbs.lattice_gibbs_sweep` (which is
-                         the special case "king's lattice + 4-coloring +
-                         stencil shifts instead of index gathers").
+`colored_gibbs_sweep` runs one full chromatic Gibbs sweep over the
+padded `SparseIsing` layout (`repro.core.sparse`), fused over all color
+phases: the arbitrary-graph generalization of
+`lattice_gibbs.lattice_gibbs_sweep` (which is the special case "king's
+lattice + 4-coloring + stencil shifts instead of index gathers").
 
 Sweep layout: sites x chains. The state is held as (n_pad, L) float32,
 one site per row and one chain per lane (L chains up to 128 per lane
@@ -37,16 +32,11 @@ The fields are the same float32 operations in the same order as
 its own color's uniform, so the kernel is bit-identical to both.
 
 Chains in lanes means a batch of B chains is one call. `colored_gibbs_lanes`
-gives the kernel a `jax.vmap` rule: `run()` sweeps each chain as the
-one-chain batch `s[None]` and maps chains, and the A mapped calls become
+folds `run()`'s map over chains (`repro.kernels.chains`): `run()` sweeps
+each chain as the one-chain batch `s[None]`, and the A mapped calls become
 one call of A lanes. A shared inverse temperature rides in SMEM; a
 per-chain one, a per-chain bias (field noise) or a per-chain update mask
 (dropped sites) becomes a per-lane operand.
-
-`sparse_fields` keeps the older layout: the site axis folded into
-(rows, 128) lane tiles, one chain at a time, and a gather that broadcasts
-every state row to every output row, O(rows^2) vreg work per slot and
-chain. It runs in no sampler path.
 """
 from __future__ import annotations
 
@@ -58,100 +48,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import tracing
+from repro.kernels import chains
 
-_LANES = 128
-_VREG_SITES = 8 * _LANES  # the site axis is padded to whole (8, 128) vregs
-
-
-def _tiled(x: jax.Array, n_pad: int, fill) -> jax.Array:
-    """Pad the last (site) axis to n_pad and fold it into (n_pad/128, 128)."""
-    pad = [(0, 0)] * (x.ndim - 1) + [(0, n_pad - x.shape[-1])]
-    x = jnp.pad(x, pad, constant_values=fill)
-    return x.reshape(x.shape[:-1] + (n_pad // _LANES, _LANES))
-
-
-def _tiled_tables(nbr_idx, nbr_w, b, n_pad):
-    """(md, R, 128) lane index, row index and weight per slot; (R, 128) bias."""
-    idx = _tiled(nbr_idx.T, n_pad, 0)
-    return idx % _LANES, idx // _LANES, _tiled(nbr_w.T, n_pad, 0), _tiled(b, n_pad, 0)
-
-
-def _chain_fields(s_ref, chain, lo_ref, hi_ref, w_ref, b):
-    """(R, 128) local fields of one chain of the block."""
-    rows, lanes = b.shape
-    h = None
-    for k in range(lo_ref.shape[0]):
-        lo, hi = lo_ref[k], hi_ref[k]
-
-        def pick(r, g):
-            src = jnp.broadcast_to(s_ref[chain, pl.ds(r, 1), :], (rows, lanes))
-            return jnp.where(hi == r, jnp.take_along_axis(src, lo, axis=1), g)
-
-        g = jax.lax.fori_loop(0, rows, pick, jnp.zeros((rows, lanes), s_ref.dtype))
-        term = w_ref[k] * g  # slot by slot, in slot_sum's order
-        h = term if h is None else h + term
-    return h + b
-
-
-def _fields_kernel(s_ref, lo_ref, hi_ref, w_ref, b_ref, out_ref):
-    b = b_ref[...]
-
-    def chain(i, carry):
-        out_ref[i] = _chain_fields(s_ref, i, lo_ref, hi_ref, w_ref, b)
-        return carry
-
-    jax.lax.fori_loop(0, s_ref.shape[0], chain, 0)
-
-
-def _check_block_batch(name: str, B: int, bb: int) -> None:
-    # ValueError, not assert: must fail fast with a readable message (and
-    # survive `python -O`) instead of an opaque Pallas grid error.
-    if B % bb != 0:
-        raise ValueError(
-            f"{name}: batch {B} is not divisible by block_batch {bb}; pass a "
-            f"block_batch that divides the batch (or a batch that is a "
-            f"multiple of block_batch)"
-        )
-
-
-def _padded_sites(n: int) -> int:
-    return -(-n // _VREG_SITES) * _VREG_SITES
-
-
-@functools.partial(jax.jit, static_argnames=("block_batch", "interpret"))
-def sparse_fields(
-    s: jax.Array,        # (B, n) f32 ±1
-    nbr_idx: jax.Array,  # (n, max_deg) int32
-    nbr_w: jax.Array,    # (n, max_deg) f32
-    b: jax.Array,        # (n,) f32
-    *,
-    block_batch: int = 8,
-    interpret: bool = True,
-) -> jax.Array:
-    B, n = s.shape
-    bb = min(block_batch, B)
-    _check_block_batch("sparse_fields", B, bb)
-    n_pad = _padded_sites(n)
-    lo, hi, w, b_t = _tiled_tables(nbr_idx, nbr_w, b, n_pad)
-    md, R, L = lo.shape
-    out = pl.pallas_call(
-        _fields_kernel,
-        grid=(B // bb,),
-        in_specs=[
-            pl.BlockSpec((bb, R, L), lambda i: (i, 0, 0)),
-            pl.BlockSpec((md, R, L), lambda i: (0, 0, 0)),
-            pl.BlockSpec((md, R, L), lambda i: (0, 0, 0)),
-            pl.BlockSpec((md, R, L), lambda i: (0, 0, 0)),
-            pl.BlockSpec((R, L), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, R, L), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, R, L), nbr_w.dtype),
-        interpret=interpret,
-    )(_tiled(s, n_pad, 1), lo, hi, w, b_t)
-    return out.reshape(B, n_pad)[:, :n]
-
-
+_LANES = 128  # the lanes of a vreg: chains per lane block
 # Sites a step of a color's walk takes: one sublane tile of the state.
 _BLOCK = 8
 # Blocks per iteration of the walk's loop: the scheduler overlaps one
@@ -367,61 +266,29 @@ def colored_gibbs_sweep(
     return out[:n, :B].T
 
 
-def _noted_sweep(s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan, *, calls, **kw):
-    """`colored_gibbs_sweep`, noting its chains in `tracing` while it is traced."""
-    chains = s.shape[0]
-    tracing.note_rows("colored_gibbs_sweep", chains, -(-chains // _LANES) * _LANES, calls)
-    return colored_gibbs_sweep(s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan, **kw)
+def _lanes_of(A, mapped, s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan):
+    """The A mapped calls of B chains as one call of A*B lanes. A bias, mask
+    or beta that differs between chains (field noise, dropped sites, a
+    per-chain schedule) becomes a per-chain operand; a shared one stays
+    shared. The transposes to and from the kernel's sites x chains layout
+    happen inside the call."""
+    s_m, _, _, b_m, u_m, masks_m, beta_m, _ = mapped
+    B, n = s.shape[-2:]
+    C = uniforms.shape[-3]
+    s = chains.fold_operand(s, s_m, A, (B, n))
+    u = chains.fold_operand(uniforms, u_m, A, (C, B, n), axis=1)
+    if b_m or b.ndim == 2:  # per-chain biases
+        b = chains.fold_operand(b, b_m, A, (B, n))
+    if masks_m or masks.ndim == 3:  # per-chain update masks
+        masks = chains.fold_operand(masks, masks_m, A, (C, B, n), axis=1)
+    if beta_m or beta.ndim:  # per-chain inverse temperatures
+        beta = chains.fold_operand(beta, beta_m, A, (B,))
+    return s, nbr_idx, nbr_w, b, u, masks, beta, plan
 
 
-def colored_gibbs_lanes(s, nbr_idx, nbr_w, b, uniforms, masks, beta=None, plan=None, **kw):
-    """`colored_gibbs_sweep`, whose `jax.vmap` maps chains to the kernel's lanes.
-
-    Called on its own it is `colored_gibbs_sweep` (keyword arguments go to
-    it). Under `jax.vmap` — `run()` maps each chain's scan over chains, and
-    a chain sweeps as the one-chain batch `s[None]` — the A mapped calls of
-    B chains become ONE call of A*B chains. A bias, mask or beta that
-    differs between chains (field noise, dropped sites, a per-chain
-    schedule) becomes a per-chain operand; a shared one stays shared. The
-    transposes to and from the kernel's sites x chains layout happen inside
-    the call. Mapped tables or plan (never in `run()`) fall back to vmap's
-    default rule: one call per mapped element, as a grid axis.
-
-    Each trace notes the chains per call, the lanes they fill (padded to
-    128), and the calls per sweep in `tracing.row_occupancy`.
-    """
-    beta = jnp.ones((), jnp.float32) if beta is None else jnp.asarray(beta, jnp.float32)
-
-    @jax.custom_batching.custom_vmap
-    def sweep(s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan):
-        return _noted_sweep(s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan, calls=1, **kw)
-
-    @sweep.def_vmap
-    def as_lanes(A, in_batched, s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan):
-        s_m, idx_m, w_m, b_m, u_m, masks_m, beta_m, plan_m = in_batched
-        if idx_m or w_m or any(jax.tree_util.tree_leaves(plan_m)):
-            call = functools.partial(_noted_sweep, calls=A, **kw)
-            axes = [0 if m else None for m in in_batched[:7]]
-            axes.append(jax.tree_util.tree_map(lambda m: 0 if m else None, plan_m))
-            return jax.vmap(call, in_axes=axes)(
-                s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan
-            ), True
-        B, n = s.shape[-2:]
-        C = uniforms.shape[-3]
-        chains = lambda x: jnp.broadcast_to(x, (A, B, n)).reshape(A * B, n)
-        s = chains(s)
-        u = uniforms if u_m else jnp.broadcast_to(uniforms, (A, C, B, n))
-        u = jnp.moveaxis(u, 0, 1).reshape(C, A * B, n)
-        if b_m or b.ndim == 2:  # per-chain biases
-            b = chains(b.reshape(A, -1, n) if b_m else b)
-        if masks_m or masks.ndim == 3:  # per-chain update masks
-            m = masks if masks_m else jnp.broadcast_to(masks, (A,) + masks.shape)
-            m = m.reshape(A, C, -1, n)
-            masks = jnp.moveaxis(jnp.broadcast_to(m, (A, C, B, n)), 0, 1).reshape(C, A * B, n)
-        if beta_m or beta.ndim:  # per-chain inverse temperatures
-            beta = beta.reshape(A, -1) if beta_m else beta
-            beta = jnp.broadcast_to(beta, (A, B)).reshape(A * B)
-        out = _noted_sweep(s, nbr_idx, nbr_w, b, u, masks, beta, plan, calls=1, **kw)
-        return out.reshape(A, B, n), True
-
-    return sweep(s, nbr_idx, nbr_w, b, uniforms, masks, beta, plan)
+# `colored_gibbs_sweep` with `run()`'s chains in its lanes
+# (`repro.kernels.chains`); mapped tables or plan take one call per mapped
+# element.
+colored_gibbs_lanes = chains.batched(
+    colored_gibbs_sweep, "colored_gibbs_sweep", tile=_LANES, shared=(1, 2, 7), fold=_lanes_of
+)

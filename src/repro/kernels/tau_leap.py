@@ -7,11 +7,11 @@ This is the throughput kernel for large SK/MaxCut sampling sweeps; the
 chip analogue is "synapse + neuron + latch" operating concurrently.
 
 Chains are the kernel's rows. `run()` steps each chain as one row
-(`s[None]`) and maps chains with `jax.vmap`; `tau_leap_rows` turns that map
-into one call whose rows are the chains: `n_chains` rows, padded to a
-multiple of the MXU's 128-row tile, with J read once per tile and step.
-`n_chains=1` is not mapped and keeps the one-row call. The rows of each
-traced call are noted in `repro.core.tracing.row_occupancy`.
+(`s[None]`) and maps chains with `jax.vmap`; `tau_leap_rows` folds that map
+(`repro.kernels.chains`) into one call whose rows are the chains:
+`n_chains` rows, padded to a multiple of the MXU's 128-row tile, with J
+read once per tile and step. `n_chains=1` is not mapped and keeps the
+one-row call.
 """
 from __future__ import annotations
 
@@ -22,8 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import tracing
-from repro.kernels.dense_field import _pad_to
+from repro.kernels import chains
 
 ROW_TILE = 128  # the MXU's rows: the default block_b
 
@@ -61,6 +60,16 @@ def _tau_leap_kernel(
         rate = jax.nn.sigmoid(2.0 * h * s)
         p_flip = 1.0 - jnp.exp(-dt_ref[0] * rate)
         out_ref[...] = jnp.where(u_ref[...] < p_flip, -s, s)
+
+
+def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
+    """`x` with zeros appended on `axis` up to a multiple of `mult`."""
+    rem = (-x.shape[axis]) % mult
+    if rem == 0:
+        return x
+    pads = [(0, 0)] * x.ndim
+    pads[axis] = (0, rem)
+    return jnp.pad(x, pads)
 
 
 def _tile(n: int, most: int) -> int:
@@ -146,49 +155,24 @@ def tau_leap_step(
     return out[:B, :N]
 
 
-def _noted_step(s, j_i8, b, scale, uniforms, dt, *, calls, block_b=ROW_TILE, **kw):
-    """`tau_leap_step`, noting its rows in `tracing` while it is traced."""
-    rows = s.shape[0]
-    tracing.note_rows("tau_leap_step", rows, -(-rows // block_b) * block_b, calls)
-    return tau_leap_step(s, j_i8, b, scale, uniforms, dt, block_b=block_b, **kw)
+def _rows(A, mapped, s, j_i8, b, scale, uniforms, dt):
+    """The A mapped calls of B rows as one call of A*B rows, J read once per
+    row tile. A scale or bias that differs between chains (a per-chain
+    schedule scales both, field noise shifts the bias) becomes a per-row
+    operand; a shared one stays shared."""
+    s_m, _, b_m, scale_m, u_m, _ = mapped
+    B, N = s.shape[-2:]
+    if scale_m or scale.ndim:  # per-row scales
+        scale = chains.fold_operand(scale, scale_m, A, (B,))
+    if b_m or b.ndim == 2:  # per-row biases
+        b = chains.fold_operand(b, b_m, A, (B, N))
+    s = chains.fold_operand(s, s_m, A, (B, N))
+    uniforms = chains.fold_operand(uniforms, u_m, A, (B, N))
+    return s, j_i8, b, scale, uniforms, dt
 
 
-def tau_leap_rows(s, j_i8, b, scale, uniforms, dt, **kw):
-    """`tau_leap_step`, whose `jax.vmap` maps chains to the kernel's rows.
-
-    Called on its own it is `tau_leap_step` (keyword arguments go to it).
-    Under `jax.vmap` — `run()` maps each chain's scan over chains, and a
-    chain steps as the one row `s[None]` — the A mapped calls of B rows
-    become ONE call of A*B rows, so the MXU's row tile carries up to
-    `block_b` chains and J is read once per row tile, not once per chain.
-    A scale or bias that differs between chains (a per-chain schedule scales
-    both, field noise shifts the bias) becomes a per-row operand; a shared
-    one stays shared. Mapped couplings or `dt` (never in `run()`) fall back
-    to vmap's default rule: one call per mapped element, as a grid axis.
-
-    Each trace notes the kernel's rows per call, the padded rows the MXU
-    processes, and the calls per step in `tracing.row_occupancy`.
-    """
-
-    @jax.custom_batching.custom_vmap
-    def step(s, j_i8, b, scale, uniforms, dt):
-        return _noted_step(s, j_i8, b, scale, uniforms, dt, calls=1, **kw)
-
-    @step.def_vmap
-    def as_rows(A, in_batched, s, j_i8, b, scale, uniforms, dt):
-        s_m, j_m, b_m, scale_m, u_m, dt_m = in_batched
-        if j_m or dt_m:
-            call = functools.partial(_noted_step, calls=A, **kw)
-            axes = [0 if m else None for m in in_batched]
-            return jax.vmap(call, in_axes=axes)(s, j_i8, b, scale, uniforms, dt), True
-        B, N = s.shape[-2:]
-        rows = lambda x: jnp.broadcast_to(x, (A, B, N)).reshape(A * B, N)
-        if scale_m or scale.ndim:  # per-row scales
-            scale = scale.reshape(A, -1) if scale_m else scale
-            scale = jnp.broadcast_to(scale, (A, B)).reshape(A * B)
-        if b_m or b.ndim == 2:  # per-row biases
-            b = rows(b.reshape(A, -1, N) if b_m else b)
-        out = _noted_step(rows(s), j_i8, b, scale, rows(uniforms), dt, calls=1, **kw)
-        return out.reshape(A, B, N), True
-
-    return step(s, j_i8, b, scale, uniforms, dt)
+# `tau_leap_step` with `run()`'s chains in its rows (`repro.kernels.chains`);
+# mapped couplings or `dt` take one call per mapped element.
+tau_leap_rows = chains.batched(
+    tau_leap_step, "tau_leap_step", tile=ROW_TILE, shared=(1, 5), fold=_rows
+)

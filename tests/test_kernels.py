@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.ising import king_color_masks
-from repro.kernels import dense_field as df
 from repro.kernels import lattice_gibbs as lg
 from repro.kernels import ops
 from repro.kernels import ref
@@ -77,7 +76,7 @@ def test_lattice_gibbs_tiles_with_faults_match_ref(block_rows):
     def kernel(s, b, u, upd, bt):
         return ops.lattice_gibbs_sweep(
             s, w, b, u, upd.astype(jnp.float32), frozen.astype(jnp.float32), clampv, bt,
-            mode="kernel", block_batch=1, block_rows=block_rows,
+            block_batch=1, block_rows=block_rows,
         )
 
     def oracle(s, b, u, upd, bt):
@@ -119,41 +118,28 @@ def test_lattice_gibbs_calls_noted(chains):
 
 
 @pytest.mark.parametrize(
-    "B,N,blocks",
-    [
-        (8, 64, (8, 64, 64)),      # padding path: N < 128
-        (128, 128, (128, 128, 128)),
-        (64, 300, (64, 128, 128)), # non-divisible N -> padded
-        (130, 256, (128, 128, 128)),  # non-divisible B
-    ],
-)
-def test_dense_field_kernel_matches_ref(B, N, blocks):
-    bb, bn, bk = blocks
-    k = jax.random.split(jax.random.key(1), 3)
-    s = _rand_pm1(k[0], (B, N)).astype(jnp.int8)
-    J = jax.random.randint(k[1], (N, N), -127, 128, jnp.int32).astype(jnp.int8)
-    b = jax.random.normal(k[2], (N,))
-    scale = jnp.asarray(0.0173, jnp.float32)
-    got = df.dense_field(s, J, b, scale, block_b=bb, block_n=bn, block_k=bk, interpret=True)
-    want = ref.dense_field_ref(s, J, b, scale)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
-
-
-@pytest.mark.parametrize(
     "B,N,per_row,block",
     [
-        pytest.param(8, 64, (), 64, id="8-64"),
-        pytest.param(32, 200, (), 64, id="32-200"),
-        pytest.param(128, 128, (), 64, id="128-128"),
+        pytest.param(8, 64, (), (64, 64, 64), id="8-64"),
+        pytest.param(32, 200, (), (64, 64, 64), id="32-200"),
+        pytest.param(128, 128, (), (64, 64, 64), id="128-128"),
         # per-row operands, as a batched run() passes them
-        pytest.param(8, 64, ("scale",), 64, id="8-64-row_scale"),
-        pytest.param(32, 200, ("bias",), 64, id="32-200-row_bias"),
-        pytest.param(130, 128, ("scale", "bias"), 64, id="130-128-row_scale_bias"),
+        pytest.param(8, 64, ("scale",), (64, 64, 64), id="8-64-row_scale"),
+        pytest.param(32, 200, ("bias",), (64, 64, 64), id="32-200-row_bias"),
+        pytest.param(130, 128, ("scale", "bias"), (64, 64, 64), id="130-128-row_scale_bias"),
         # the kernel's own blocks: N padded to 1024, 512 columns by 1024 sites
         pytest.param(8, 1000, ("scale", "bias"), None, id="8-1000-default_blocks"),
+        # the int8 matmul's tiling: N below one block, one block, N padded,
+        # B not a multiple of block_b, several k blocks accumulated in int32
+        pytest.param(8, 64, (), (8, 64, 64), id="8-64-blocks8x64x64"),
+        pytest.param(128, 128, (), (128, 128, 128), id="128-128-blocks128"),
+        pytest.param(64, 300, (), (64, 128, 128), id="64-300-blocks64x128x128"),
+        pytest.param(130, 256, (), (128, 128, 128), id="130-256-blocks128"),
+        pytest.param(16, 96, (), (16, 32, 32), id="16-96-blocks16x32x32"),
     ],
 )
 def test_tau_leap_kernel_matches_ref(B, N, per_row, block):
+    """Bit-parity with the int32-accumulating oracle at every blocking."""
     k = jax.random.split(jax.random.key(2), 6)
     s = _rand_pm1(k[0], (B, N))
     J = jax.random.randint(k[1], (N, N), -127, 128, jnp.int32).astype(jnp.int8)
@@ -165,7 +151,7 @@ def test_tau_leap_kernel_matches_ref(B, N, per_row, block):
         scale = scale * jax.random.uniform(k[4], (B,), minval=0.1, maxval=3.0)
     if "bias" in per_row:
         b = b + jax.random.normal(k[5], (B, N)) * 0.5
-    blocks = {} if block is None else dict(block_b=block, block_n=block, block_k=block)
+    blocks = {} if block is None else dict(zip(("block_b", "block_n", "block_k"), block))
     got = tl.tau_leap_step(s, J, b, scale, u, dt, interpret=True, **blocks)
     want = ref.tau_leap_step_ref(s, J, b, scale, u, dt)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0)
@@ -185,20 +171,6 @@ def test_tau_leap_row_occupancy(C):
     sampler_api.run(prob, sampler_api.TauLeap(dt=0.25), jax.random.key(0), n_steps=5,
                     n_chains=C, backend="pallas")
     assert tracing.row_occupancy("tau_leap_step") == tracing.RowOccupancy(C, 128, 1)
-
-
-def test_dense_field_int8_exactness():
-    """int8 path is exact integer arithmetic — zero float error vs numpy."""
-    rng = np.random.default_rng(0)
-    B, N = 16, 96
-    s = (2 * rng.integers(0, 2, (B, N)) - 1).astype(np.int8)
-    J = rng.integers(-127, 128, (N, N)).astype(np.int8)
-    acc = s.astype(np.int64) @ J.T.astype(np.int64)
-    got = df.dense_field(
-        jnp.asarray(s), jnp.asarray(J), jnp.zeros((N,)), jnp.asarray(1.0, jnp.float32),
-        block_b=16, block_n=32, block_k=32, interpret=True,
-    )
-    np.testing.assert_array_equal(np.asarray(got), acc.astype(np.float32))
 
 
 def test_quantize_dense_roundtrip():
@@ -260,8 +232,8 @@ def test_lattice_gibbs_beta_default_is_one():
 
 
 def test_ops_lattice_gibbs_eager_block_batch_validation():
-    """mode='kernel' with a batch the block doesn't divide must fail fast
-    with a readable ValueError, not an opaque Pallas grid error at trace."""
+    """A batch the block doesn't divide must fail fast with a readable
+    ValueError, not an opaque Pallas grid error at trace."""
     B, H, W = 6, 8, 8
     s = jnp.ones((B, H, W))
     w = jnp.zeros((8, H, W))
@@ -272,11 +244,11 @@ def test_ops_lattice_gibbs_eager_block_batch_validation():
     clampv = jnp.ones((H, W))
     with pytest.raises(ValueError, match="block_batch"):
         ops.lattice_gibbs_sweep(
-            s, w, b, u, colors, frozen, clampv, mode="kernel", block_batch=4
+            s, w, b, u, colors, frozen, clampv, block_batch=4
         )
     # a dividing block is fine
     out = ops.lattice_gibbs_sweep(
-        s, w, b, u, colors, frozen, clampv, mode="kernel", block_batch=3
+        s, w, b, u, colors, frozen, clampv, block_batch=3
     )
     assert out.shape == (B, H, W)
 
@@ -318,20 +290,6 @@ def _rand_sparse_tables(key, n, density=0.4):
                                                     b=b.astype(jnp.float32)))
 
 
-@pytest.mark.parametrize("B,n", [(4, 16), (8, 48), (2, 100)])
-def test_sparse_fields_kernel_matches_ref(B, n):
-    from repro.kernels import sparse_gather as sg
-
-    sp = _rand_sparse_tables(jax.random.key(20), n)
-    s = _rand_pm1(jax.random.key(21), (B, n))
-    got = sg.sparse_fields(s, sp.nbr_idx, sp.nbr_w, sp.b, interpret=True,
-                           block_batch=2)
-    want = ref.sparse_fields_ref(s, sp.nbr_idx, sp.nbr_w, sp.b)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # ...and both equal the problem's own local_fields, bit-for-bit
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(sp.local_fields(s)))
-
-
 @pytest.mark.parametrize("beta", [None, 0.3, 1.0, 3.0])
 def test_colored_gibbs_kernel_matches_ref_beta(beta):
     """Colored sweep: ref <-> pallas(interpret) bit-parity at every
@@ -355,24 +313,19 @@ def test_colored_gibbs_kernel_matches_ref_beta(beta):
 
 
 def test_ops_sparse_eager_block_batch_validation():
-    """mode='kernel' with a block the kernel cannot take must fail fast with
-    a readable ValueError, not an opaque Pallas grid error: the colored
-    sweep's lane block (its chains) must be a multiple of 128 lanes."""
+    """A block the kernel cannot take must fail fast with a readable
+    ValueError, not an opaque Pallas grid error: the colored sweep's lane
+    block (its chains) must be a multiple of 128 lanes."""
     sp = _rand_sparse_tables(jax.random.key(25), 12)
     C = sp.color_masks.shape[0]
     s = jnp.ones((6, 12))
     u = jnp.zeros((C, 6, 12))
     masks = sp.color_masks.astype(jnp.float32)
     with pytest.raises(ValueError, match="block_lanes"):
-        ops.colored_gibbs_sweep(s, sp.nbr_idx, sp.nbr_w, sp.b, u, masks,
-                                mode="kernel", block_lanes=4)
-    with pytest.raises(ValueError, match="block_batch"):
-        ops.sparse_fields(s, sp.nbr_idx, sp.nbr_w, sp.b, mode="kernel", block_batch=5)
-    # a whole lane block is fine, and matches the reference mode bit-for-bit
-    out = ops.colored_gibbs_sweep(s, sp.nbr_idx, sp.nbr_w, sp.b, u, masks,
-                                  mode="kernel", block_lanes=128)
-    want = ops.colored_gibbs_sweep(s, sp.nbr_idx, sp.nbr_w, sp.b, u, masks,
-                                   mode="reference")
+        ops.colored_gibbs_sweep(s, sp.nbr_idx, sp.nbr_w, sp.b, u, masks, block_lanes=4)
+    # a whole lane block is fine, and matches the oracle bit-for-bit
+    out = ops.colored_gibbs_sweep(s, sp.nbr_idx, sp.nbr_w, sp.b, u, masks, block_lanes=128)
+    want = ref.colored_gibbs_sweep_ref(s, sp.nbr_idx, sp.nbr_w, sp.b, u, sp.color_masks)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
@@ -451,8 +404,8 @@ def test_colored_gibbs_vmap_matches_ref(case):
     if case == "chain_masks":  # drops: each chain keeps its own sites
         keep = jax.random.bernoulli(keys[3], 0.7, (A, 1, n))
         masks, axes[5] = sp.color_masks[None, :, None] & keep[:, None], 0
-    kernel = lambda *a: ops.colored_gibbs_sweep(*a, mode="kernel")
-    oracle = lambda *a: ops.colored_gibbs_sweep(*a, mode="reference")
+    kernel = ops.colored_gibbs_sweep
+    oracle = lambda s, i, w, b, u, m, bt: ref.colored_gibbs_sweep_ref(s, i, w, b, u, m > 0.5, bt)
     args = (s, sp.nbr_idx, sp.nbr_w, b, u, masks.astype(jnp.float32), beta)
     got = jax.vmap(kernel, in_axes=axes)(*args)
     assert tracing.row_occupancy("colored_gibbs_sweep") == tracing.RowOccupancy(A, 128, 1)
@@ -460,12 +413,13 @@ def test_colored_gibbs_vmap_matches_ref(case):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("A", [2, 6])
+@pytest.mark.parametrize("A", [1, 2, 6])
 def test_colored_gibbs_row_occupancy(A):
     """run() on A chains puts them in the lanes of ONE kernel call per
-    sweep: A of 128 lanes, where each chain used to be a call of its own.
-    The note is made while the program is traced, so each case runs a
-    shape no other test runs."""
+    sweep: A of 128 lanes, where each chain used to be a call of its own;
+    one chain is not mapped and is noted the same way. The note is made
+    while the program is traced, so each case runs a shape no other test
+    runs."""
     from repro.core import sampler_api, tracing
 
     sp = _irregular_graph(19, seed=A)
@@ -474,17 +428,52 @@ def test_colored_gibbs_row_occupancy(A):
     assert tracing.row_occupancy("colored_gibbs_sweep") == tracing.RowOccupancy(A, 128, 1)
 
 
-def test_ops_auto_uses_reference_on_cpu():
-    """ops.* 'auto' mode must agree with the kernel path bit-for-bit."""
-    B, N = 8, 64
-    k = jax.random.split(jax.random.key(6), 3)
-    s = _rand_pm1(k[0], (B, N)).astype(jnp.int8)
-    J = jax.random.randint(k[1], (N, N), -127, 128, jnp.int32).astype(jnp.int8)
-    b = jax.random.normal(k[2], (N,))
-    scale = jnp.asarray(0.01, jnp.float32)
-    auto = ops.dense_field(s, J, b, scale)
-    kern = ops.dense_field(s, J, b, scale, mode="kernel", block_b=8, block_n=64, block_k=64)
-    np.testing.assert_allclose(np.asarray(auto), np.asarray(kern), rtol=1e-6, atol=1e-5)
+FALLBACK_KERNELS = ["tau_leap_step", "colored_gibbs_sweep", "lattice_gibbs_sweep"]
+
+
+@pytest.mark.parametrize("kernel", FALLBACK_KERNELS)
+def test_chains_rule_falls_back_when_a_shared_operand_is_mapped(kernel):
+    """A map over an operand the kernel reads once for all its chains (the
+    couplings, the neighbour weights, the lattice's weight planes) takes
+    vmap's default rule: one call per mapped element, noted as A calls,
+    each equal to the oracle."""
+    from repro.core import tracing
+
+    A = 3
+    ks = jax.random.split(jax.random.key(31), 5)
+    if kernel == "tau_leap_step":
+        N = 40
+        J = jax.random.randint(ks[1], (A, N, N), -127, 128, jnp.int32).astype(jnp.int8)
+        args = (_rand_pm1(ks[0], (A, 1, N)), J, jax.random.normal(ks[2], (N,)) * 0.2,
+                jnp.asarray(1.0 / 127.0, jnp.float32), jax.random.uniform(ks[3], (A, 1, N)),
+                jnp.asarray(0.3, jnp.float32))
+        axes = (0, 0, None, None, 0, None)
+        op, oracle, tile = ops.tau_leap_step, ref.tau_leap_step_ref, 128
+    elif kernel == "colored_gibbs_sweep":
+        sp = _irregular_graph(17, seed=4)
+        C, n = sp.color_masks.shape
+        w = sp.nbr_w * jnp.arange(1.0, A + 1.0)[:, None, None]
+        args = (_rand_pm1(ks[0], (A, 1, n)), sp.nbr_idx, w, sp.b,
+                jax.random.uniform(ks[1], (A, C, 1, n)), sp.color_masks.astype(jnp.float32),
+                jnp.asarray(0.7, jnp.float32))
+        axes = (0, None, 0, None, 0, None, None)
+        op, tile = ops.colored_gibbs_sweep, 128
+        oracle = lambda s, i, w, b, u, m, bt: ref.colored_gibbs_sweep_ref(s, i, w, b, u, m > 0.5, bt)
+    else:
+        H = W = 16
+        frozen = jax.random.bernoulli(ks[4], 0.2, (H, W))
+        args = (_rand_pm1(ks[0], (A, 1, H, W)), jax.random.normal(ks[1], (A, 8, H, W)) * 0.5,
+                jax.random.normal(ks[2], (H, W)) * 0.3, jax.random.uniform(ks[3], (A, 4, 1, H, W)),
+                king_color_masks(H, W).astype(jnp.float32), frozen.astype(jnp.float32),
+                _rand_pm1(jax.random.key(32), (H, W)), jnp.asarray(0.9, jnp.float32))
+        axes = (0, 0, None, 0, None, None, None, None)
+        op, tile = ops.lattice_gibbs_sweep, 1
+        oracle = lambda s, w, b, u, c, f, cv, bt: ref.lattice_gibbs_sweep_ref(
+            s, w, b, u, c > 0.5, f > 0.5, cv, bt)
+    got = jax.vmap(op, in_axes=axes)(*args)
+    assert tracing.row_occupancy(kernel) == tracing.RowOccupancy(1, tile, A)
+    want = jax.vmap(oracle, in_axes=axes)(*args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize(

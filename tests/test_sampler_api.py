@@ -164,26 +164,39 @@ def _masked_lattice(H=10, W=10, seed=0):
     )
 
 
-def test_chromatic_pallas_executes_lattice_gibbs_sweep(monkeypatch):
-    """Acceptance: backend='pallas' on chromatic_gibbs must actually execute
-    ops.lattice_gibbs_sweep — the dispatch used to silently no-op to ref."""
+PALLAS_KERNELS = [
+    pytest.param(ChromaticGibbs(), "lattice_gibbs_sweep", id="chromatic_gibbs"),
+    pytest.param(ColoredGibbs(), "colored_gibbs_sweep", id="colored_gibbs"),
+    pytest.param(TauLeap(dt=0.25), "tau_leap_step", id="tau_leap"),
+]
+
+
+@pytest.mark.parametrize("kernel,op", PALLAS_KERNELS)
+def test_chromatic_pallas_executes_lattice_gibbs_sweep(monkeypatch, kernel, op):
+    """Acceptance: backend='pallas' must actually execute the kernel class's
+    Pallas kernel through `ops` (the dispatch once silently fell back to
+    ref), and backend='ref' must not touch it."""
     from repro.kernels import ops
 
     calls = []
-    orig = ops.lattice_gibbs_sweep
+    orig = getattr(ops, op)
 
     def spy(*args, **kw):
-        calls.append(kw.get("mode"))
+        calls.append(args[0].shape)
         return orig(*args, **kw)
 
-    monkeypatch.setattr(ops, "lattice_gibbs_sweep", spy)
+    monkeypatch.setattr(ops, op, spy)
+    problem = {
+        "lattice_gibbs_sweep": _masked_lattice,
+        "colored_gibbs_sweep": lambda: problems.random_3regular_maxcut(14, seed=2),
+        "tau_leap_step": lambda: _dense_problem(n=11),
+    }[op]()
     # n_steps=11 is used by no other test: the driver's jit cache cannot
     # already hold this signature, so tracing (and the spy) must run.
-    lat = _masked_lattice()
-    run(lat, ChromaticGibbs(), jax.random.key(0), n_steps=11, backend="pallas")
-    assert calls and all(m == "kernel" for m in calls)
+    run(problem, kernel, jax.random.key(0), n_steps=11, backend="pallas")
+    assert calls
     calls.clear()
-    run(lat, ChromaticGibbs(), jax.random.key(0), n_steps=11, backend="ref")
+    run(problem, kernel, jax.random.key(0), n_steps=11, backend="ref")
     assert calls == []
 
 

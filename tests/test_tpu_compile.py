@@ -20,7 +20,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.ising import N_KING_COLORS
-from repro.kernels import dense_field, lattice_gibbs, sparse_gather, tau_leap
+from repro.kernels import lattice_gibbs, sparse_gather, tau_leap
 
 
 @pytest.fixture(scope="module")
@@ -73,12 +73,6 @@ def test_tau_leap_step_per_row_scale_bias_compiles_n2048_b128(one_chip):
         one_chip, ((B, N), F32), ((N, N), I8), ((B, N), F32), ((B,), F32), ((B, N), F32), ((), F32)
     )
     _assert_kernel_compiles(tau_leap.tau_leap_step, args)
-
-
-def test_dense_field_compiles_n2048_b128(one_chip):
-    B, N = 128, 2048
-    args = _shapes(one_chip, ((B, N), I8), ((N, N), I8), ((N,), F32), ((), F32))
-    _assert_kernel_compiles(dense_field.dense_field, args)
 
 
 def test_lattice_gibbs_sweep_compiles_128x128_b8(one_chip):
@@ -158,8 +152,3 @@ def test_colored_gibbs_lanes_compiles_n4096_deg3(one_chip, A, chain_beta):
 
     assert "tpu_custom_call" in mapped.lower(*args).compile().as_text()
 
-
-def test_sparse_fields_compiles_n4096_deg8_b8(one_chip):
-    B, n, md = SPARSE_B, SPARSE_N, SPARSE_MD
-    args = _shapes(one_chip, ((B, n), F32), ((n, md), I32), ((n, md), F32), ((n,), F32))
-    _assert_kernel_compiles(sparse_gather.sparse_fields, args, block_batch=8)
