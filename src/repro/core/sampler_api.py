@@ -69,6 +69,7 @@ outputs bit-for-bit at beta=1.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from functools import partial
 from typing import Any, NamedTuple, Optional, Protocol, Union, runtime_checkable
@@ -1264,6 +1265,15 @@ def _run_batched(
     return jax.vmap(one, in_axes=in_axes)(keys, s0, betas)
 
 
+@jax.jit
+def _probe_energy(problem) -> jax.Array:
+    """Energy of the all-ones state: `run()`'s finite-energy probe as one
+    compiled program. It compiles once per problem type and shapes and is
+    rerun on every call, so a problem whose values change between calls
+    (PCD learning's J) is checked as it is passed."""
+    return problem.energy(jnp.ones(state_shape(problem)))
+
+
 def _resolve_unroll(unroll, kernel, problem) -> int:
     """Resolve the event-block size: "auto" asks the kernel (CTMC blocks
     events on big problems), an int is validated and used as-is."""
@@ -1345,6 +1355,12 @@ def run(
         — results are bit-identical to a run that never passed the
         argument, for every kernel and backend.
 
+    Raises NonFiniteEnergyError before any sampling when the problem's
+    energy (after fault binding) is NaN/Inf. The probe is one compiled
+    program (the energy of the all-ones state) and one scalar read per
+    call that runs; it checks the values passed on that call, and is
+    skipped when an outer `jax.jit` traces `run()`.
+
     Each call that runs (is not traced by an outer `jax.jit`) opens the
     host spans `run`, `run.validate`, `run.prep` and `run.call` and leaves
     one record of their durations: see `repro.core.tracing`. They touch
@@ -1371,12 +1387,14 @@ def run(
             # is a tracer and the check is skipped — concreteness is gone,
             # and the caller's own entry into jit already went through an
             # un-traced run() or can probe explicitly.
-            e_probe = problem.energy(jnp.ones(state_shape(problem)))
-            if not isinstance(e_probe, jax.core.Tracer) and not bool(jnp.isfinite(e_probe)):
-                raise NonFiniteEnergyError(
-                    f"problem energy is non-finite (probe energy {float(e_probe)}); "
-                    "check the couplings/biases (and any FaultModel) for NaN/Inf"
-                )
+            e_probe = _probe_energy(problem)
+            if not isinstance(e_probe, jax.core.Tracer):
+                e_probe = float(e_probe)
+                if not math.isfinite(e_probe):
+                    raise NonFiniteEnergyError(
+                        f"problem energy is non-finite (probe energy {e_probe}); "
+                        "check the couplings/biases (and any FaultModel) for NaN/Inf"
+                    )
 
         with span("run.prep"):
             betas = resolve_schedule(schedule, n_steps, n_chains)

@@ -360,6 +360,37 @@ def test_run_raises_non_finite_energy_error():
     assert issubclass(NonFiniteEnergyError, ValueError)
 
 
+def _poisoned(problem, part, value):
+    """`problem` with one coupling (`part="coupling"`) or one bias set to
+    `value`; the coupling symmetrically where the layout stores it twice."""
+    if isinstance(problem, ising.DenseIsing):
+        if part == "coupling":
+            return dataclasses.replace(problem, J=problem.J.at[0, 1].set(value).at[1, 0].set(value))
+        return dataclasses.replace(problem, b=problem.b.at[2].set(value))
+    if isinstance(problem, SparseIsing):
+        if part == "coupling":
+            return dataclasses.replace(problem, nbr_w=problem.nbr_w.at[0, 0].set(value))
+        return dataclasses.replace(problem, b=problem.b.at[2].set(value))
+    if part == "coupling":
+        return dataclasses.replace(problem, w=problem.w.at[0, 2, 2].set(value))
+    return dataclasses.replace(problem, b=problem.b.at[2, 2].set(value))
+
+
+@pytest.mark.parametrize("make", [_dense, _sparse, _lattice], ids=["dense", "sparse", "lattice"])
+@pytest.mark.parametrize("part,value", [("coupling", np.inf), ("bias", np.nan)])
+def test_run_raises_non_finite_energy_error_on_every_call(make, part, value):
+    """Every problem kind, NaN/Inf in a coupling or in the bias: run()
+    raises before sampling, and again on a later call with a poisoned
+    problem of the same shapes after a finite one — the probe checks the
+    values passed on each call, with nothing kept per problem."""
+    finite = make()
+    with pytest.raises(NonFiniteEnergyError, match="non-finite"):
+        run(_poisoned(finite, part, value), "tau_leap", jax.random.key(0), n_steps=2)
+    assert np.isfinite(np.asarray(run(finite, "tau_leap", jax.random.key(1), n_steps=2).s)).all()
+    with pytest.raises(NonFiniteEnergyError, match="non-finite"):
+        run(_poisoned(finite, part, -value), "tau_leap", jax.random.key(2), n_steps=2)
+
+
 def test_run_probe_skipped_under_trace():
     """run() stays traceable: the non-finite probe is host-side only, so a
     jitted caller (e.g. the tempering loop) must not hit a tracer-bool

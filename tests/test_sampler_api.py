@@ -621,6 +621,32 @@ def test_timeit_reports_throughput_and_identical_results():
     )
 
 
+def test_finite_energy_probe_is_one_compiled_program():
+    """run()'s probe compiles once per problem type and shapes: a second
+    problem with the same shapes and other values reuses the program."""
+    sampler_api._probe_energy.clear_cache()
+    for seed in (1, 2):
+        run(_dense_problem(n=20, seed=seed), "tau_leap", jax.random.key(seed), n_steps=2)
+    assert sampler_api._probe_energy._cache_size() == 1
+
+
+@pytest.mark.parametrize(
+    "make",
+    [
+        lambda: _dense_problem(n=20, seed=3),
+        lambda: problems.random_3regular_maxcut(16, seed=4),
+        lambda: problems.cal_problem(coupling=0.5),
+    ],
+    ids=["dense", "sparse", "lattice"],
+)
+def test_finite_energy_probe_matches_eager_energy(make):
+    problem = make()
+    eager = problem.energy(jnp.ones(sampler_api.state_shape(problem)))
+    np.testing.assert_allclose(
+        float(sampler_api._probe_energy(problem)), float(eager), rtol=1e-6
+    )
+
+
 def test_run_error_paths():
     prob = _dense_problem(n=8, seed=0)
     with pytest.raises(KeyError, match="unknown sampler kernel"):
